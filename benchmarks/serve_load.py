@@ -38,6 +38,7 @@ sys.path.insert(
     0, os.path.join(os.path.dirname(__file__), "..", "src")
 )
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.serve import (  # noqa: E402
     add_engine_args,
     build_engine,
@@ -229,6 +230,7 @@ def main() -> None:
         if rc != 0:
             raise SystemExit(rc)
 
+    enable_compile_cache()
     engine = build_engine(args)
     rng = np.random.default_rng(args.seed)
     arrivals = np.cumsum(rng.exponential(1.0 / args.rate, args.requests))

@@ -20,7 +20,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true", help="smaller input")
     args = ap.parse_args()
-    n = 64 if args.fast else 192
+    # a power of two: the GA's staged loop variants are radix-2 only
+    n = 64 if args.fast else 256
 
     from repro.apps import fourier
     from repro.core import run_ga

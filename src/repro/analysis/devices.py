@@ -123,11 +123,7 @@ def probe_device_envelope(device=None) -> DeviceEnvelope:
 
     if device is None:
         device = jax.devices()[0]
-    stats = None
-    try:
-        stats = device.memory_stats()
-    except Exception:  # noqa: BLE001 — older backends raise instead
-        stats = None
+    stats = device.memory_stats()
     limit = 0
     if stats:
         limit = int(

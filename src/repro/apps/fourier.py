@@ -36,11 +36,21 @@ def _bit_reverse_indices(n: int) -> list[int]:
     return out
 
 
+def dft1d_nr(row):
+    """Direct O(n^2) DFT: the library's path for lengths radix-2 cannot split."""
+    n = len(row)
+    k = np.arange(n)
+    return np.exp(-2j * np.pi * np.outer(k, k) / n) @ np.asarray(
+        row, dtype=np.complex128
+    )
+
+
 def fft1d_nr(row):
-    """Radix-2 in-place FFT of one complex vector (Numerical Recipes four1)."""
+    """Radix-2 in-place FFT of one complex vector (Numerical Recipes four1);
+    a length that is not a power of two takes the direct DFT."""
     n = len(row)
     if n & (n - 1):
-        raise ValueError("length must be a power of two")
+        return dft1d_nr(row)
     data = row.copy()
     # bit-reversal permutation
     j = 0
@@ -99,7 +109,7 @@ def fft2d_nr(x):
 def fft1d_nr(row):
     n = len(row)
     if n & (n - 1):
-        raise ValueError("length must be a power of two")
+        return dft1d_nr(row)
     data = row.copy()
     j = 0
     for i in range(n):

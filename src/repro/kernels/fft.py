@@ -28,7 +28,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro.kernels.matmul import pad_to, tile_dim
 
 
 def dft_matrix(n: int, sign: float = -1.0) -> tuple[np.ndarray, np.ndarray]:
@@ -45,18 +45,16 @@ def _cmm_kernel(ar_ref, ai_ref, br_ref, bi_ref, or_ref, oi_ref,
         accr_ref[...] = jnp.zeros_like(accr_ref)
         acci_ref[...] = jnp.zeros_like(acci_ref)
 
+    dot = functools.partial(
+        jnp.dot, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # exact f32 DFT (see matmul)
+    )
     ar = ar_ref[...]
     ai = ai_ref[...]
     br = br_ref[...]
     bi = bi_ref[...]
-    accr_ref[...] += (
-        jnp.dot(ar, br, preferred_element_type=jnp.float32)
-        - jnp.dot(ai, bi, preferred_element_type=jnp.float32)
-    )
-    acci_ref[...] += (
-        jnp.dot(ar, bi, preferred_element_type=jnp.float32)
-        + jnp.dot(ai, br, preferred_element_type=jnp.float32)
-    )
+    accr_ref[...] += dot(ar, br) - dot(ai, bi)
+    acci_ref[...] += dot(ar, bi) + dot(ai, br)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -100,7 +98,7 @@ def complex_matmul_pallas(
             pltpu.VMEM((block_m, block_n), jnp.float32),
             pltpu.VMEM((block_m, block_n), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -109,22 +107,33 @@ def complex_matmul_pallas(
 
 def fft2d_pallas(x: jax.Array, *, interpret: bool = False,
                  block: int = 128) -> jax.Array:
-    """2-D FFT of a complex array via two DFT matmul stages."""
+    """2-D FFT of a complex array via two DFT matmul stages.
+
+    Any (n, m): each axis pads to its :func:`tile_dim` extent, and the DFT
+    operands are zero-padded along the contraction, so the padded rows and
+    columns contribute nothing and the result is the top-left (n, m).
+    """
     n, m = x.shape
-    xr = jnp.real(x).astype(jnp.float32)
-    xi = jnp.imag(x).astype(jnp.float32)
-    fr_m, fi_m = dft_matrix(m)
+    np_, bn = tile_dim(n, block)
+    mp, bm = tile_dim(m, block)
+    xr = pad_to(jnp.real(x).astype(jnp.float32), (np_, mp))
+    xi = pad_to(jnp.imag(x).astype(jnp.float32), (np_, mp))
+
+    def dft(k: int, kp: int) -> tuple[jax.Array, jax.Array]:
+        fr, fi = dft_matrix(k)
+        return (pad_to(jnp.asarray(fr), (kp, kp)),
+                pad_to(jnp.asarray(fi), (kp, kp)))
+
     # rows: X @ F_m  (F symmetric)
+    fr_m, fi_m = dft(m, mp)
     yr, yi = complex_matmul_pallas(
-        xr, xi, jnp.asarray(fr_m), jnp.asarray(fi_m),
-        block_m=min(block, n), block_n=min(block, m), block_k=min(block, m),
+        xr, xi, fr_m, fi_m, block_m=bn, block_n=bm, block_k=bm,
         interpret=interpret,
     )
-    fr_n, fi_n = dft_matrix(n)
     # columns: F_n @ Y == (Y^T @ F_n)^T
+    fr_n, fi_n = dft(n, np_)
     zr, zi = complex_matmul_pallas(
-        yr.T, yi.T, jnp.asarray(fr_n), jnp.asarray(fi_n),
-        block_m=min(block, m), block_n=min(block, n), block_k=min(block, n),
+        yr.T, yi.T, fr_n, fi_n, block_m=bm, block_n=bn, block_k=bn,
         interpret=interpret,
     )
-    return (zr.T + 1j * zi.T).astype(jnp.complex64)
+    return (zr.T[:n, :m] + 1j * zi.T[:n, :m]).astype(jnp.complex64)

@@ -31,6 +31,10 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+#: f32 contractions run exact: TPU's default f32 matmul is one bfloat16
+#: pass, too coarse for the app-level verify (rtol 1e-3)
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def _panel_factor(panel: jax.Array, n_real_rows: int):
     """Unblocked LU of a (rows x nb) panel, pivoting over all rows.
@@ -89,14 +93,14 @@ def _trsm_lower_unit(l11: jax.Array, b: jax.Array) -> jax.Array:
 
     def body(r, x):
         lrow = jnp.where(ridx < r, l11[r], 0.0)  # (nb,)
-        x_r = b[r] - lrow @ x
+        x_r = b[r] - jnp.dot(lrow, x, precision=_EXACT)
         return x.at[r].set(x_r)
 
     return jax.lax.fori_loop(0, nb, body, jnp.zeros_like(b))
 
 
 def _schur_jnp(c: jax.Array, a: jax.Array, b: jax.Array) -> jax.Array:
-    return c - a @ b
+    return c - jnp.dot(a, b, precision=_EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "n_real", "use_pallas", "interpret"))
@@ -119,17 +123,12 @@ def lu_blocked(
     n_real = n if n_real is None else n_real
 
     if use_pallas:
-        from repro.kernels.matmul import schur_update_pallas
+        from repro.kernels.matmul import schur_update_padded
 
         def schur(c, x, y):
             if min(c.shape + x.shape) == 0:
                 return c
-            bm = 128 if c.shape[0] % 128 == 0 else nb
-            return schur_update_pallas(
-                c, x, y, block_m=min(bm, c.shape[0]),
-                block_n=min(128, c.shape[1]), block_k=min(128, x.shape[1]),
-                interpret=interpret,
-            )
+            return schur_update_padded(c, x, y, interpret=interpret)
     else:
         schur = _schur_jnp
 

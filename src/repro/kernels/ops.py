@@ -19,7 +19,7 @@ from repro.kernels.attention import flash_attention_pallas
 from repro.kernels import paged_attention as _paged
 from repro.kernels.fft import dft_matrix, fft2d_pallas
 from repro.kernels.lu import lu_blocked
-from repro.kernels.matmul import matmul_pallas, schur_update_pallas
+from repro.kernels.matmul import matmul_padded, schur_update_padded
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd import ssd_chunks_pallas
 
@@ -37,13 +37,13 @@ def matmul(a, b, *, backend: str | None = None, interpret: bool = False):
     a = jnp.asarray(a)
     b = jnp.asarray(b)
     if _auto_backend(backend) == "pallas":
-        return matmul_pallas(a, b, interpret=interpret)
+        return matmul_padded(a, b, interpret=interpret)
     return _ref.matmul_ref(a, b)
 
 
 def schur_update(c, a, b, *, backend: str | None = None, interpret: bool = False):
     if _auto_backend(backend) == "pallas":
-        return schur_update_pallas(c, a, b, interpret=interpret)
+        return schur_update_padded(c, a, b, interpret=interpret)
     return _ref.schur_update_ref(c, a, b)
 
 

@@ -47,8 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 _NEG = -1e30
 
 
@@ -341,7 +339,7 @@ def paged_attention_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, r, dv), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -5,7 +5,9 @@ activation is read from HBM once (XLA emits separate reduce + mul passes at
 f32 widths unless it fuses; the kernel makes the fusion structural).
 
 Block: (rows_block, d) — the whole feature dim stays in VMEM (d <= 8192 f32
-= 32 KiB/row), rows_block chosen so the block is ~1 MiB.
+= 32 KiB/row).  A row block is either all the rows or a multiple of 8 (the
+TPU sublane tile) with the rows zero-padded to a whole number of blocks, so
+every row count lowers; padded rows normalise to zero and are sliced away.
 """
 
 from __future__ import annotations
@@ -38,12 +40,12 @@ def rmsnorm_pallas(
     rows = 1
     for s in orig_shape[:-1]:
         rows *= s
-    x2 = x.reshape(rows, d)
-    rb = rows_block
-    while rows % rb:
-        rb //= 2
-    rb = max(rb, 1)
-    grid = (rows // rb,)
+    if rows_block % 8:
+        raise ValueError(f"rows_block {rows_block} is not a multiple of 8")
+    rb = min(rows, rows_block)
+    padded = -(-rows // rb) * rb
+    x2 = jnp.pad(x.reshape(rows, d), ((0, padded - rows), (0, 0)))
+    grid = (padded // rb,)
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=grid,
@@ -52,7 +54,7 @@ def rmsnorm_pallas(
             pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((padded, d), x.dtype),
         interpret=interpret,
     )(x2, w.reshape(1, d))
-    return out.reshape(orig_shape)
+    return out[:rows].reshape(orig_shape)

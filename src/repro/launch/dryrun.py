@@ -299,8 +299,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             if v is not None:
                 rec[attr] = int(v)
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, list):  # older jax returns [per-device dict]
-            cost = cost[0] if cost else {}
         rec["xla_flops_per_device"] = float(cost.get("flops", 0.0))
         rec["xla_bytes_per_device"] = float(cost.get("bytes accessed", 0.0))
         txt = compiled.as_text()

@@ -18,12 +18,20 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (tests use small ones, e.g. (2,4) on 8 host devices)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests use small ones, e.g. (2,4) on 8 host devices).
+
+    Every axis is ``Auto``: the sharding code places arrays with
+    ``with_sharding_constraint`` and leaves propagation to GSPMD, while
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which indexing
+    a sharded array (``emb[tokens]``) raises ``DuplicateSpecError``.
+    """
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def mesh_shape_dict(mesh) -> dict[str, int]:

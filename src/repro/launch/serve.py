@@ -27,6 +27,7 @@ import sys
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Tracer
 from repro.serve import Request, Sampler, ServeEngine
 
@@ -277,6 +278,7 @@ def main(argv: "list[str] | None" = None) -> int:
     if args.preflight:
         return preflight(args)
 
+    enable_compile_cache()
     engine = build_engine(args)
     rng = np.random.default_rng(args.seed)
     requests = make_requests(engine.cfg, args, rng)

@@ -2,9 +2,8 @@
 
 Wires together: config -> synthetic data pipeline -> jitted train step ->
 fault-tolerant loop (async checkpoints, restart/replay, straggler monitor).
-On this CPU container it runs reduced configs end-to-end (see
-examples/train_lm.py); on hardware the same driver takes the production
-mesh via --mesh.
+It runs on one device: reduced configs end-to-end on a CPU (see
+examples/train_lm.py), full configs on one accelerator.
 
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b --reduced \
       --steps 100 --batch 8 --seq 128
@@ -32,6 +31,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLMData
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import TrainHyper, make_train_step
 from repro.models import lm
 from repro.optim.adamw import AdamW
@@ -105,6 +105,7 @@ def main() -> None:
                     help="power telemetry for the run (and --plan-search): "
                          "none | auto | time | nvml | rapl | psutil")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.metering import meter_window, resolve_meter
 

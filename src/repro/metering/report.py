@@ -291,12 +291,14 @@ def _selftest_stores(root: str) -> tuple[str, str]:
     )
     from repro.core.planner.objectives import PowerMeter
 
-    # fast-but-hungry vs slow-but-frugal: the classic trade-off cell
+    # fast-but-hungry vs slow-but-frugal: the classic trade-off cell.  The
+    # latency gap (10 ms) is wide enough that sleep overshoot on a loaded
+    # host does not reorder the candidates.
     costs = {
-        frozenset(): (0.008, 40.0),
-        frozenset({"fft"}): (0.002, 300.0),  # latency winner
-        frozenset({"lu"}): (0.004, 60.0),  # perf-per-watt winner
-        frozenset({"fft", "lu"}): (0.003, 250.0),
+        frozenset(): (0.040, 40.0),
+        frozenset({"fft"}): (0.010, 300.0),  # latency winner
+        frozenset({"lu"}): (0.020, 60.0),  # perf-per-watt winner
+        frozenset({"fft", "lu"}): (0.015, 250.0),
     }
 
     def build(subset):

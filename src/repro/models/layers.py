@@ -27,7 +27,6 @@ BF16_TP_REDUCE = False
 
 def tp_out_einsum(spec: str, a: jax.Array, b: jax.Array, cd) -> jax.Array:
     """Einsum 'bsq,qd->bsd'-shaped, contraction crossing the TP shards."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.utils import current_mesh, current_rules, resolve_spec
@@ -59,9 +58,9 @@ def tp_out_einsum(spec: str, a: jax.Array, b: jax.Array, cd) -> jax.Array:
             )
         return jax.lax.psum(part, "model")
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(in_a, in_b), out_specs=out,
-        check_rep=False,
+        check_vma=False,
     )(a, b)
 
 
@@ -111,7 +110,6 @@ MEGATRON_MLP = False
 
 
 def _megatron_mlp(p: dict, x: jax.Array, cd) -> jax.Array:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.utils import current_mesh, current_rules, resolve_spec
@@ -141,12 +139,12 @@ def _megatron_mlp(p: dict, x: jax.Array, cd) -> jax.Array:
             )
         return jax.lax.psum(part, "model")
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(xs, P(None, "model"), P(None, "model"), P("model", None)),
         out_specs=xs,
-        check_rep=False,
+        check_vma=False,
     )(
         x.astype(cd),
         p["gate"].astype(cd),

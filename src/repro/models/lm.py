@@ -194,25 +194,6 @@ def init_cache(
 # -- block application -------------------------------------------------------------
 
 
-@jax.custom_jvp
-def _opt_barrier(x):
-    """``lax.optimization_barrier`` with an identity differentiation rule.
-
-    The barrier is a scheduling hint, not a math op, so its tangent is the
-    identity — but jax (< 0.4.38) ships no differentiation rule for the
-    primitive at all, which kills the train-step backward pass under
-    ``value_and_grad``.  The custom JVP keeps the barrier in the primal
-    computation and lets tangents flow through untouched.
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-@_opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    (x,), (dx,) = primals, tangents
-    return _opt_barrier(x), dx
-
-
 def _apply_attn_block(
     lp: dict, x: jax.Array, cfg: ArchConfig, positions, cache, index, mode,
     kind: str, pages=None,
@@ -227,14 +208,14 @@ def _apply_attn_block(
     # barrier after the bf16 cast: the SP->TP all-gather must happen on
     # the bf16 post-norm tensor, not be hoisted above the cast into the
     # norm's f32 internals (which doubles transition bytes)
-    h_in = _opt_barrier(
+    h_in = jax.lax.optimization_barrier(
         rmsnorm(lp["ln1"], x, cfg.norm_eps).astype(cd)
     )
     attn_out, new_cache = attention_forward(
         lp["attn"], h_in, cfg, positions, cache, index, mode, pages
     )
     x = x + attn_out.astype(x.dtype)
-    ff_in = _opt_barrier(
+    ff_in = jax.lax.optimization_barrier(
         rmsnorm(lp["ln2"], x, cfg.norm_eps).astype(cd)
     )
     if kind == "a" and cfg.moe is not None:
@@ -255,7 +236,7 @@ def _apply_mamba_block(lp, x, cfg, cache, mode):
             "boundaries"
         )
     cd = jnp.dtype(cfg.compute_dtype)
-    h_in = _opt_barrier(
+    h_in = jax.lax.optimization_barrier(
         rmsnorm(lp["ln"], x, cfg.norm_eps).astype(cd)
     )
     out, new_state = ssm_forward(lp["mixer"], h_in, cfg, cache, mode)
@@ -287,7 +268,7 @@ def _apply_group(
         # barrier: prevents XLA from hoisting dtype converts of the stacked
         # layer-input residuals out of the scan (an f32 copy of every
         # saved carry doubles remat memory otherwise)
-        x = _opt_barrier(x)
+        x = jax.lax.optimization_barrier(x)
         if shared:
             lp, lcache = None, xs
         elif use_cache:

@@ -16,7 +16,7 @@ substrate those decisions (and their operators) consume:
             (:class:`MetricsServer`; ``ServeEngine.serve_metrics(port)``).
   profile   :func:`profile_window` — opt-in ``jax.profiler`` capture
             around N serve steps or one planner round, degrading to a
-            no-op where the profiler is unavailable.
+            no-op when a capture cannot start.
   timeline  ``python -m repro.obs.timeline trace.json`` — terminal span
             summary (p50/p99 per span kind) plus the critical path of the
             worst request.
@@ -27,7 +27,7 @@ from repro.obs.metrics import (  # noqa: F401
     MetricsServer,
     exponential_buckets,
 )
-from repro.obs.profile import profile_window, profiler_available  # noqa: F401
+from repro.obs.profile import profile_window  # noqa: F401
 from repro.obs.trace import (  # noqa: F401
     NULL_SPAN,
     SpanRecord,

@@ -5,8 +5,9 @@ slow" questions; when the answer is inside a compiled program, the next
 tool down is the XLA profiler.  :func:`profile_window` brackets a code
 region with ``jax.profiler.start_trace``/``stop_trace`` so the captured
 TensorBoard/Perfetto artifacts land in a log directory, and degrades to a
-no-op (with one warning) on hosts whose jax build lacks the profiler —
-profiling must never be the reason a serve loop cannot run.
+no-op (with one warning) when a capture cannot start, e.g. because one is
+already running — profiling must never be the reason a serve loop cannot
+run.
 
 Typical uses::
 
@@ -22,19 +23,7 @@ import contextlib
 import warnings
 from typing import Iterator
 
-__all__ = ["profile_window", "profiler_available"]
-
-
-def profiler_available() -> bool:
-    """True when this jax build exposes the trace-capture profiler API."""
-    try:
-        import jax.profiler
-
-        return hasattr(jax.profiler, "start_trace") and hasattr(
-            jax.profiler, "stop_trace"
-        )
-    except Exception:  # noqa: BLE001 — absence is an answer, not an error
-        return False
+__all__ = ["profile_window"]
 
 
 @contextlib.contextmanager
@@ -44,7 +33,7 @@ def profile_window(
     """Capture a ``jax.profiler`` trace of the body into ``logdir``.
 
     Yields True when a capture is actually running, False on graceful
-    degrade (no profiler in this jax build, or a capture already active).
+    degrade (the capture could not start, e.g. one is already active).
     When ``tracer`` (a :class:`repro.obs.Tracer`) is given, the window is
     also recorded as a host-side span so the two timelines line up.
     """

@@ -21,7 +21,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _quantize(g: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -55,12 +54,12 @@ def compressed_psum_mean(grads: Any, mesh: Mesh, axis: str = "data") -> Any:
 
     spec = P(axis)
     every = jax.tree.map(lambda _: P(*([None])), grads)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_reduce,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), grads),),
         out_specs=jax.tree.map(lambda _: P(), grads),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(grads)
 

@@ -36,8 +36,6 @@ def test_scan_multiplies_trip_count():
     assert r["flops"] == pytest.approx(2 * 64**3 * L)
     # XLA's own analysis misses the loop factor — that's why this exists
     xla = jax.jit(f).lower(x, ws).compile().cost_analysis()
-    if isinstance(xla, list):  # older jax returns [per-device dict]
-        xla = xla[0]
     assert xla["flops"] == pytest.approx(2 * 64**3, rel=1e-3)
 
 

@@ -59,3 +59,15 @@ def test_fft2d_xla_backend(rng):
     np.testing.assert_allclose(
         np.asarray(out), np.fft.fft2(x).astype(np.complex64), rtol=1e-4, atol=1e-3
     )
+
+
+@pytest.mark.parametrize("n,m", [(192, 192), (192, 64), (100, 256)])
+def test_fft2d_pallas_pads_untiled_shapes(n, m, rng):
+    # n=192 does not tile by 128: the wrapper zero-pads the DFT operands
+    x = (rng.standard_normal((n, m)) + 1j * rng.standard_normal((n, m))).astype(
+        np.complex64
+    )
+    out = ops.fft2d(jnp.asarray(x), backend="pallas", interpret=True)
+    assert out.shape == (n, m)
+    want = np.fft.fft2(x)
+    assert np.abs(np.asarray(out) - want).max() / np.abs(want).max() < 1e-5

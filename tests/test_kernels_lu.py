@@ -50,3 +50,16 @@ def test_lu_identity_padding_never_pivots_into_pad(rng):
     assert int(jnp.max(piv_p)) < 100
     rec = ref.lu_reconstruct(lu_p, piv_p)
     np.testing.assert_allclose(np.asarray(rec), np.asarray(a), atol=5e-5)
+
+
+@pytest.mark.parametrize("n", [192, 256])
+def test_lu_pallas_default_block_pads_schur(n):
+    # the default nb=32 leaves trailing updates of 160, 224, ... columns:
+    # the Schur kernel pads them to its tiles
+    a = jnp.asarray(matrix.make_input(n, seed=n + 2), jnp.float32)
+    lu_p, piv = ops.lu(a, backend="pallas", interpret=True)
+    lu_x, piv_x = ops.lu(a, backend="xla")
+    np.testing.assert_array_equal(np.asarray(piv), np.asarray(piv_x))
+    np.testing.assert_allclose(np.asarray(lu_p), np.asarray(lu_x), atol=5e-5)
+    rec = ref.lu_reconstruct(lu_p, piv)
+    np.testing.assert_allclose(np.asarray(rec), np.asarray(a), atol=5e-5)

@@ -55,3 +55,27 @@ def test_block_shape_sweep(rng):
             a, b, block_m=bm, block_n=bn, block_k=bk, interpret=True
         )
         np.testing.assert_allclose(np.asarray(out), want, atol=2e-4)
+
+
+@pytest.mark.parametrize("m,k,n", [(192, 192, 192), (17, 200, 300)])
+def test_matmul_wrapper_pads_untiled_shapes(m, k, n, rng):
+    # the ops wrapper zero-pads to the kernel's tiles and slices back
+    from repro.kernels import ops
+
+    a = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    b = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
+    out = ops.matmul(a, b, backend="pallas", interpret=True)
+    assert out.shape == (m, n)
+    want = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-4)
+
+
+def test_schur_update_wrapper_pads_untiled_shapes(rng):
+    from repro.kernels import ops
+
+    c = jnp.asarray(rng.standard_normal((160, 160)), jnp.float32)
+    a = jnp.asarray(rng.standard_normal((160, 32)), jnp.float32)
+    b = jnp.asarray(rng.standard_normal((32, 160)), jnp.float32)
+    out = ops.schur_update(c, a, b, backend="pallas", interpret=True)
+    want = ref.schur_update_ref(c, a, b)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-4)

@@ -46,3 +46,22 @@ def test_rmsnorm_output_scale_invariant():
     a = rmsnorm_pallas(x, w, interpret=True)
     b = rmsnorm_pallas(x * 1000.0, w, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3)
+
+
+@pytest.mark.parametrize("rows,rows_block", [(17, 8), (5, 8), (33, 16)])
+def test_rmsnorm_any_row_count(rows, rows_block, rng):
+    # row blocks are all rows or a multiple of 8 (rows zero-padded), so
+    # every row count has a legal TPU block
+    x = jnp.asarray(rng.standard_normal((rows, 256)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal(256), jnp.float32)
+    out = rmsnorm_pallas(x, w, rows_block=rows_block, interpret=True)
+    assert out.shape == (rows, 256)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref.rmsnorm_ref(x, w)), atol=1e-5
+    )
+
+
+def test_rmsnorm_rejects_untiled_row_block():
+    x = jnp.ones((16, 128), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        rmsnorm_pallas(x, jnp.ones(128), rows_block=6, interpret=True)

@@ -68,3 +68,14 @@ def test_decay_mask_is_causal(rng):
     np.testing.assert_allclose(
         np.asarray(y1[:, :48]), np.asarray(y2[:, :48]), atol=1e-5
     )
+
+
+@pytest.mark.parametrize("h", [5, 80])
+def test_pallas_head_major_layout_any_head_count(h, rng):
+    # head counts that are not multiples of 8 (mamba2-2.7b has 80 heads of
+    # 64): the kernel's head-major blocks must still match the oracle
+    args = _inputs(rng, b=1, s=64, h=h, p=8, n=8)
+    y_ref, h_ref = ref.ssd_ref(*args)
+    y, hf = ops.ssd_scan(*args, chunk=32, backend="pallas", interpret=True)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(hf), np.asarray(h_ref), atol=2e-5)

@@ -1,16 +1,16 @@
 """Numerics of the manual-TP (shard_map) paths vs the GSPMD default.
 
 Runs in a subprocess with 8 forced host devices so a real (data=2, model=4)
-mesh exercises all_gather / psum_scatter.
+mesh exercises all_gather / psum_scatter.  The child inherits the parent's
+environment and pins the CPU platform and the device count itself.
 """
 
+import os
 import pathlib
 import subprocess
 import sys
 
 SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -22,13 +22,14 @@ from repro.models import layers as lay
 from repro.sharding.specs import rules_for
 from repro.sharding.utils import use_sharding
 from repro.configs.base import ShapeConfig
+from repro.launch.mesh import make_mesh
 
 cfg = dataclasses.replace(
     get_config("llama3.2-1b").reduced(),
     n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, d_head=16, d_ff=128,
     vocab_size=512, compute_dtype="float32", remat="none",
 )
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 shape = ShapeConfig("t", 16, 4, "train")
 rules = rules_for(cfg, shape, {"data": 2, "model": 4})
 rules["act_seq"] = "model"  # force SP so psum_scatter paths engage
@@ -73,12 +74,17 @@ print("MANUAL_TP_OK", l0, l1)
 
 def test_manual_tp_matches_gspmd():
     root = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (str(root / "src"), env.get("PYTHONPATH")) if p
+    )
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True,
         text=True,
-        env={"PYTHONPATH": str(root / "src"), "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
+        env=env,
         timeout=560,
     )
     assert out.returncode == 0, out.stderr[-3000:]

@@ -1,0 +1,146 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e, at real widths.
+
+No chip is needed: the TPU compiler is installed and compiles for a topology
+that is described, not attached.  Each test lowers and compiles one kernel
+(through its public ``ops`` wrapper where one exists) and checks that the
+compiled program holds the Mosaic kernel (``tpu_custom_call``).  What the
+chip's compiler refuses (blocks that do not tile, too much VMEM) fails here
+at no chip time.  Interpret-mode parity tests cannot see either.
+
+The topology is described inside a module fixture, never at import time:
+only one process may load the TPU library, and every pytest worker imports
+this file.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.ssd import ssd_chunks_pallas
+
+BF16 = jnp.bfloat16
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip cannot read entries back from a persistent cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, one_chip, *shapes):
+    """Compile ``fn`` for the described chip; return the compiled text."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+# llama3.2-1b serving widths: 32 heads, 8 KV heads, head dim 64, page 16,
+# 8 slots of 1024 tokens
+SLOTS, H, KH, D, PAGE, MAX_PAGES = 8, 32, 8, 64, 16, 64
+N_POOL = SLOTS * MAX_PAGES + 1  # + the null page
+
+
+@pytest.mark.parametrize("s", [1, 16], ids=["decode", "extend"])
+def test_paged_attention_gqa_compiles(one_chip, s):
+    _compile(
+        paged_attention_pallas, one_chip,
+        ((SLOTS, H, s, D), BF16),
+        ((N_POOL, KH, PAGE, D), BF16),
+        ((N_POOL, KH, PAGE, D), BF16),
+        ((SLOTS, MAX_PAGES), jnp.int32),
+        ((SLOTS,), jnp.int32),
+    )
+
+
+def test_paged_attention_mla_decode_compiles(one_chip):
+    # deepseek-v2 absorbed decode: 128 heads over one latent "KV head" of
+    # rank 512, plus the 64-wide decoupled rope channel
+    heads, rank, rope = 128, 512, 64
+
+    def mla(q, c_pool, q_rope, kr_pool, pages, index):
+        return paged_attention_pallas(
+            q, c_pool, c_pool, pages, index, q_rope=q_rope, kr_pool=kr_pool,
+            scale=1.0 / (128 + rope) ** 0.5,
+        )
+
+    _compile(
+        mla, one_chip,
+        ((SLOTS, heads, 1, rank), BF16),
+        ((N_POOL, 1, PAGE, rank), BF16),
+        ((SLOTS, heads, 1, rope), BF16),
+        ((N_POOL, 1, PAGE, rope), BF16),
+        ((SLOTS, MAX_PAGES), jnp.int32),
+        ((SLOTS,), jnp.int32),
+    )
+
+
+def test_flash_attention_compiles(one_chip):
+    fn = functools.partial(ops.flash_attention, backend="pallas")
+    _compile(
+        fn, one_chip,
+        ((1, H, 2048, D), BF16), ((1, KH, 2048, D), BF16),
+        ((1, KH, 2048, D), BF16),
+    )
+
+
+@pytest.mark.parametrize(
+    "m,k,n,dtype",
+    [(2048, 2048, 8192, BF16), (192, 192, 192, F32)],
+    ids=["2048x8192-bf16", "192-f32"],
+)
+def test_matmul_compiles(one_chip, m, k, n, dtype):
+    fn = functools.partial(ops.matmul, backend="pallas")
+    _compile(fn, one_chip, ((m, k), dtype), ((k, n), dtype))
+
+
+def test_rmsnorm_odd_rows_compiles(one_chip):
+    fn = functools.partial(ops.rmsnorm, backend="pallas")
+    _compile(fn, one_chip, ((17, 2048), BF16), ((2048,), BF16))
+
+
+def test_ssd_mamba2_widths_compiles(one_chip):
+    # mamba2-2.7b: d_inner 5120 = 80 heads x 64, state 128, chunk 128
+    b, s, h, p, n = 1, 512, 80, 64, 128
+    fn = functools.partial(ssd_chunks_pallas, chunk=128)
+    _compile(
+        fn, one_chip,
+        ((b, s, h, p), F32), ((b, s, h), F32), ((h,), F32),
+        ((b, s, n), F32), ((b, s, n), F32),
+    )
+
+
+@pytest.mark.parametrize("n", [192, 256])
+def test_fft2d_compiles(one_chip, n):
+    fn = functools.partial(ops.fft2d, backend="pallas")
+    _compile(fn, one_chip, ((n, n), jnp.complex64))
+
+
+@pytest.mark.parametrize("n", [192, 256])
+def test_lu_compiles(one_chip, n):
+    fn = functools.partial(ops.lu, backend="pallas")
+    _compile(fn, one_chip, ((n, n), F32))
