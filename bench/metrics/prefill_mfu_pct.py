@@ -1,0 +1,18 @@
+"""Model FLOPs of the traced prefills (unpadded tokens, causal attention
+counted once per pair) over the device time of the ``jit_prefill_fn``
+program in the trace at the chip's peak rate."""
+
+from bench import window, work
+
+
+def read(run):
+    if run.trace is None or run.peaks is None:
+        return None
+    mod = run.trace["modules"].get("jit_prefill_fn")
+    if not mod or not mod["seconds"]:
+        return None
+    flops = sum(
+        work.prefill_flops(run.config, n) for _, pre in window.traced_steps(run.rec)
+        for n in pre
+    )
+    return 100.0 * flops / (mod["seconds"] * run.peaks["bf16_flops_per_s"])
