@@ -25,6 +25,7 @@ it has observed:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -82,9 +83,6 @@ class ProgramRecord:
     loop: bool = False  # called once per engine step (the decode loop)
     carry_outputs: tuple[int, ...] = ()  # top-level outputs that stay on device
     expected_signatures: int | None = None  # None = unbounded (e.g. prefill)
-    #: trace-span kind covering this program's calls (None = the engine
-    #: never span-instruments it — the obs info lint flags that)
-    span_kind: str | None = None
     signatures: dict[tuple, tuple] = dataclasses.field(default_factory=dict)
     calls: int = 0
     #: wall seconds spent in the first call of each distinct signature —
@@ -123,10 +121,10 @@ class ProgramSet:
         self.sync_bytes = sync_bytes
         self.const_bytes = const_bytes
         #: optional ``repro.obs`` attachments (set by the engine): a
-        #: Tracer that receives a "compile" span per new signature, and a
-        #: MetricsRegistry that carries per-program retrace/compile-time
-        #: counters.  Both default off — a bare ProgramSet stays analysis-
-        #: only with zero obs coupling.
+        #: Tracer whose live "serve.compile" span covers each first call
+        #: of a new signature, and a MetricsRegistry that carries
+        #: per-program retrace/compile-time counters.  Both default off —
+        #: a bare ProgramSet stays analysis-only with zero obs coupling.
         self.tracer: Any = None
         self.metrics: Any = None
 
@@ -137,7 +135,6 @@ class ProgramSet:
         loop: bool = False,
         carry_outputs: Sequence[int] = (),
         expected_signatures: int | None = None,
-        span_kind: str | None = None,
     ) -> Callable[..., Any]:
         """Wrap ``fn`` so calls record their abstract signature (and the
         first-call wall time of each new signature — the compile cost).
@@ -148,7 +145,6 @@ class ProgramSet:
             loop=loop,
             carry_outputs=tuple(carry_outputs),
             expected_signatures=expected_signatures,
-            span_kind=span_kind,
         )
         self.records[name] = rec
 
@@ -162,23 +158,27 @@ class ProgramSet:
             # first call under this signature: jit traces + compiles
             # synchronously inside the call, so its wall time is the
             # retrace cost (execution itself dispatches async)
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            dt = time.perf_counter() - t0
+            with self._compile_span(rec):
+                t0 = time.perf_counter()
+                out = fn(*args, **kwargs)
+                dt = time.perf_counter() - t0
             rec.compile_seconds += dt
-            self._on_compile(rec, t0, dt)
+            self._on_compile(rec, dt)
             return out
 
         observed.record = rec  # type: ignore[attr-defined]
         return observed
 
-    def _on_compile(self, rec: ProgramRecord, t0: float, dt: float) -> None:
-        tracer = self.tracer
-        if tracer is not None and tracer.enabled:
-            tracer.add_span(
-                "compile", t0, t0 + dt,
-                program=rec.name, signature=len(rec.signatures),
-            )
+    def _compile_span(self, rec: ProgramRecord) -> Any:
+        """A live span around a first call: a compile inside a profiled
+        window then shows as ``serve.compile``, not as unexplained time."""
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.span(
+            "serve.compile", program=rec.name, signature=len(rec.signatures)
+        )
+
+    def _on_compile(self, rec: ProgramRecord, dt: float) -> None:
         if self.metrics is not None:
             self.metrics.counter(
                 "serve_program_retraces_total",
@@ -202,7 +202,6 @@ class ProgramSet:
                 "signatures": len(rec.signatures),
                 "retraces": rec.retraces,
                 "compile_seconds": rec.compile_seconds,
-                "span_kind": rec.span_kind,
             }
             for name, rec in self.records.items()
         }
@@ -210,6 +209,29 @@ class ProgramSet:
     def observe(self, name: str, *args: Any) -> None:
         """Record a signature without wrapping (tests, ad-hoc programs)."""
         self.records[name].observe(args)
+
+    def compiled_texts(
+        self, names: Sequence[str] | None = None
+    ) -> dict[str, list[str]]:
+        """The compiled HLO text of each named program (default: every one
+        called so far), one per signature it was called with, keyed by the
+        text's ``HloModule`` name (``jit_decode_fn``: what a profiler
+        trace calls its runs).  The signatures of one program share that
+        name but not their instruction names: only a program with one
+        signature maps a trace's instructions without ambiguity.
+        Lowering and compiling again is served by the persistent
+        compilation cache, whose key leaves metadata out: a cache written
+        by code with other scopes hands back its text."""
+        from repro.obs.scopes import module_name
+
+        out: dict[str, list[str]] = {}
+        for name, rec in self.records.items():
+            if names is not None and name not in names:
+                continue
+            for structs in rec.signatures.values():
+                text = rec.fn.lower(*structs).compile().as_text()
+                out.setdefault(module_name(text), []).append(text)
+        return out
 
     # -- lints ---------------------------------------------------------------
 
@@ -225,18 +247,6 @@ class ProgramSet:
         diags: list[Diagnostic] = []
         if not rec.signatures:
             return diags  # never called — nothing observed to lint
-
-        if self.tracer is not None and rec.span_kind is None:
-            # the engine attached a tracer but this program's calls carry
-            # no span kind: its time is invisible in the exported timeline
-            diags.append(Diagnostic(
-                pass_name="hotpath", code="no-span", severity="info",
-                program=rec.name, subject="span-instrumentation",
-                message=(
-                    "program is registered with a traced engine but has no "
-                    "span_kind — its calls won't appear in obs timelines"
-                ),
-            ))
 
         if (
             rec.expected_signatures is not None

@@ -100,7 +100,14 @@ class FunctionBlockRegistry:
         return impls[target].fn
 
     def call(self, block: str, *args: Any, **kwargs: Any) -> Any:
-        return self.resolve(block)(*args, **kwargs)
+        """Run the bound implementation under ``jax.named_scope(block)``:
+        the block's device time keeps one name whichever target is bound
+        (``repro.obs.op_scopes`` reads it back from the compiled text)."""
+        import jax
+
+        fn = self.resolve(block)
+        with jax.named_scope(block):
+            return fn(*args, **kwargs)
 
     def current_pattern(self) -> dict[str, str]:
         return dict(self._bindings)

@@ -51,6 +51,8 @@ _NEG = -1e30
 
 
 # -- page-table plumbing (shared by both targets and the serve engine) ---------
+# the writes run under the ``kv_write`` scope, apart from the
+# ``paged_attention`` block's read: a profile can tell the two apart
 
 
 def gather_kv_pages(
@@ -84,6 +86,7 @@ def gather_kv_pages(
     return jax.lax.fori_loop(0, mp, walk, jnp.zeros(out_shape, pool.dtype))
 
 
+@jax.named_scope("kv_write")
 def scatter_token_pages(
     pool: jax.Array,
     val: jax.Array,
@@ -107,6 +110,7 @@ def scatter_token_pages(
     return pool.at[idx].set(val.astype(pool.dtype))
 
 
+@jax.named_scope("kv_write")
 def scatter_chunk_pages(
     pool: jax.Array,
     val: jax.Array,
@@ -132,6 +136,7 @@ def scatter_chunk_pages(
     return jax.lax.fori_loop(0, s, write, pool)
 
 
+@jax.named_scope("kv_write")
 def insert_pages(
     pool: jax.Array, b1: jax.Array, page_ids: jax.Array, seq_axis: int
 ) -> jax.Array:

@@ -221,7 +221,8 @@ def _apply_attn_block(
     if kind == "a" and cfg.moe is not None:
         ff, aux = moe_forward(lp["moe"], ff_in, cfg, cd)
     else:
-        ff = mlp_forward(lp["mlp"], ff_in, cd)
+        with jax.named_scope("mlp"):
+            ff = mlp_forward(lp["mlp"], ff_in, cd)
         aux = jnp.asarray(0.0, jnp.float32)
     x = x + ff.astype(x.dtype)
     x = constrain(x, "act_batch", "act_seq", None)
@@ -359,6 +360,7 @@ def backbone(
     return x, aux_total, new_cache
 
 
+@jax.named_scope("head")
 def head(params: Any, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return lm_logits(params["embed"], x, cfg, jnp.dtype(cfg.compute_dtype))

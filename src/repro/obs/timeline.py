@@ -1,7 +1,7 @@
 """Terminal span summary over an exported trace.
 
 ``python -m repro.obs.timeline trace.json`` loads a Chrome/Perfetto
-``trace_event`` JSON file (or the JSONL stream form) written by
+``trace_event`` JSON file written by
 :class:`repro.obs.Tracer` and prints:
 
 * a per-span-kind table — count, total time, p50/p99 durations — the
@@ -28,19 +28,11 @@ __all__ = ["load_events", "span_summary", "worst_request", "main"]
 
 
 def load_events(path: str) -> list[dict]:
-    """Events from a ``{"traceEvents": [...]}`` JSON file or a JSONL
-    stream (one event object per line)."""
+    """Events from a ``{"traceEvents": [...]}`` JSON file (or a bare
+    event list)."""
     with open(path) as f:
-        text = f.read()
-    try:
-        doc = json.loads(text)
-    except json.JSONDecodeError:
-        # not one document -> the JSONL stream form, one object per line
-        events = [
-            json.loads(line) for line in text.splitlines() if line.strip()
-        ]
-    else:
-        events = doc.get("traceEvents", []) if isinstance(doc, dict) else doc
+        doc = json.load(f)
+    events = doc.get("traceEvents", []) if isinstance(doc, dict) else doc
     return [e for e in events if isinstance(e, dict)]
 
 
@@ -126,12 +118,12 @@ def render(events: Sequence[dict], max_path: int = 40) -> str:
     rows = span_summary(events)
     if rows:
         lines.append(
-            f"{'span':<16} {'count':>7} {'total ms':>10} "
+            f"{'span':<18} {'count':>7} {'total ms':>10} "
             f"{'p50 ms':>9} {'p99 ms':>9}"
         )
         for r in rows:
             lines.append(
-                f"{r['name']:<16} {r['count']:>7} {r['total_ms']:>10.2f} "
+                f"{r['name']:<18} {r['count']:>7} {r['total_ms']:>10.2f} "
                 f"{r['p50_ms']:>9.3f} {r['p99_ms']:>9.3f}"
             )
     else:
@@ -168,7 +160,7 @@ def main(argv: Sequence[str] | None = None) -> int:
         prog="python -m repro.obs.timeline",
         description=__doc__.split("\n")[0],
     )
-    ap.add_argument("trace", help="trace_event JSON (or JSONL) file")
+    ap.add_argument("trace", help="trace_event JSON file")
     ap.add_argument("--check", action="store_true",
                     help="validate the trace structure; non-zero exit on "
                          "violations (CI gate)")

@@ -6,8 +6,7 @@ slow step has an explanation, not just an aggregate.  A :class:`Tracer`
 collects :class:`SpanRecord`s — complete spans (``ph="X"``), instant
 events (``ph="i"``) — into a bounded ring buffer (old records drop, the
 serve loop never blocks on its own telemetry) and exports them as
-Chrome/Perfetto ``trace_event`` JSON (open in https://ui.perfetto.dev) or
-a plain JSONL stream.
+Chrome/Perfetto ``trace_event`` JSON (open in https://ui.perfetto.dev).
 
 Two usage shapes::
 
@@ -21,12 +20,19 @@ Retroactive spans let the engine place a request's whole lifecycle
 per-request *track* from timestamps it already keeps, without holding a
 span object open across scheduler callbacks.
 
+**The profiler bridge.**  A live ``span()`` of an *enabled* tracer also
+enters ``jax.profiler.TraceAnnotation(name)`` around its body, so the
+span lands on the XLA profiler's clock beside the device's operations
+whenever a capture is running (and costs one native call when none is).
+Retroactive ``add_span`` records and instant events stay on the ring
+only: the profiler takes annotations as they happen, not after.
+
 **Disabled cost is the design constraint**: ``span()`` on a disabled
 tracer returns one shared no-op singleton (no record, no buffer touch),
-``event()``/``add_span()`` return immediately, and hot-path callers are
-expected to guard argument construction behind ``tracer.enabled``.  The
-serving benchmark's acceptance gate is that a disabled tracer is
-unmeasurable in tok/s.
+``event()``/``add_span()`` return immediately, no profiler annotation is
+made, and hot-path callers are expected to guard argument construction
+behind ``tracer.enabled``.  The serving benchmark's acceptance gate is
+that a disabled tracer is unmeasurable in tok/s.
 
 All timestamps are ``time.perf_counter()`` seconds — the same clock the
 engine stamps on requests — made relative to the tracer's ``epoch`` at
@@ -40,7 +46,7 @@ import json
 import threading
 import time
 from collections import deque
-from typing import Any, Iterable, TextIO
+from typing import Any
 
 __all__ = [
     "NULL_SPAN",
@@ -84,9 +90,10 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live ``with tracer.span(...)`` body; records itself at exit."""
+    """A live ``with tracer.span(...)`` body: a profiler annotation around
+    it, and one record on the ring at exit."""
 
-    __slots__ = ("_tracer", "name", "tid", "args", "_t0")
+    __slots__ = ("_tracer", "name", "tid", "args", "_t0", "_annotation")
 
     def __init__(
         self, tracer: "Tracer", name: str, tid: int, args: dict | None
@@ -97,14 +104,19 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        from jax.profiler import TraceAnnotation  # a disabled tracer never
+        # imports the profiler
+
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
+        t1 = time.perf_counter()
+        self._annotation.__exit__(None, None, None)
         self._tracer._record(
-            SpanRecord(
-                self.name, self._t0, time.perf_counter(), self.tid, self.args
-            )
+            SpanRecord(self.name, self._t0, t1, self.tid, self.args)
         )
         return False
 
@@ -132,8 +144,9 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, tid: int | None = None, **args: Any):
-        """Context manager timing its body into one complete span.  On a
-        disabled tracer this returns the shared :data:`NULL_SPAN` singleton
+        """Context manager timing its body into one complete span, inside a
+        ``jax.profiler.TraceAnnotation`` of the same name.  On a disabled
+        tracer this returns the shared :data:`NULL_SPAN` singleton
         (callers with expensive args should guard on :attr:`enabled`)."""
         if not self.enabled:
             return NULL_SPAN
@@ -261,27 +274,6 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
             f.write("\n")
-
-    def iter_jsonl(self) -> Iterable[str]:
-        for rec in sorted(self.records(), key=lambda r: r.t0):
-            yield json.dumps({
-                "name": rec.name,
-                "ph": rec.ph,
-                "tid": rec.tid,
-                "ts": self._ts_us(rec.t0),
-                "dur": max(rec.t1 - rec.t0, 0.0) * 1e6,
-                "args": rec.args or {},
-            })
-
-    def write_jsonl(self, path_or_file: "str | TextIO") -> None:
-        """One JSON record per line — the streaming/grep-friendly form."""
-        if hasattr(path_or_file, "write"):
-            for line in self.iter_jsonl():
-                path_or_file.write(line + "\n")
-            return
-        with open(path_or_file, "w") as f:
-            for line in self.iter_jsonl():
-                f.write(line + "\n")
 
 
 #: Module-level default tracer: disabled until someone opts in.  Library

@@ -308,18 +308,6 @@ class ServeEngine:
         self._kv_frag_g = self.registry.gauge(
             "serve_kv_fragmentation_pct", "partial-page fragmentation"
         )
-        self._capacity_fits_g = self.registry.gauge(
-            "serve_capacity_fits",
-            "1 when the last plan_capacity() verdict fit its envelope",
-        )
-        self._capacity_headroom_g = self.registry.gauge(
-            "serve_capacity_headroom_bytes",
-            "bytes of envelope headroom from the last plan_capacity()",
-        )
-        self._capacity_max_slots_g = self.registry.gauge(
-            "serve_capacity_max_slots",
-            "max slots the envelope fits at this max_len (plan_capacity)",
-        )
         self._submitted_c = self.registry.counter(
             "serve_requests_submitted_total", "requests accepted by submit()"
         )
@@ -428,21 +416,19 @@ class ServeEngine:
 
         self.programs = ProgramSet()
         # the ProgramSet shares the engine's obs attachments: new-signature
-        # calls emit "compile" spans and feed the retrace counters, and the
-        # hot-path lint can flag any program left without a span_kind
+        # calls run inside "serve.compile" spans and feed the retrace
+        # counters
         self.programs.tracer = self.tracer
         self.programs.metrics = self.registry
         self._prefill_fn = self.programs.register(
             "prefill", jax.jit(self._build_prefill()),
             carry_outputs=(1,),  # the b1 cache goes to insert, not to host
-            span_kind="prefill",
         )
         self._decode_fn = self.programs.register(
             "decode", jax.jit(self._build_decode(), donate_argnums=(2,)),
             loop=True,
             carry_outputs=(1,),  # the donated successor cache stays on device
             expected_signatures=1,  # recomposing the batch must not retrace
-            span_kind="decode",
         )
         self._insert_fn = self.programs.register(
             "insert",
@@ -452,18 +438,15 @@ class ServeEngine:
             ),
             carry_outputs=(0,),  # the whole output is the engine cache
             expected_signatures=1,  # slot recomposition must not retrace
-            span_kind="prefill",  # insert runs inside the prefill span
         )
         self._extend_fn = self.programs.register(
             "extend", jax.jit(self._build_extend(), donate_argnums=(2,)),
             carry_outputs=(0,),
-            span_kind="prefill-chunk",
         )
         self._extend_sample_fn = self.programs.register(
             "extend_sample",
             jax.jit(self._build_extend_sample(), donate_argnums=(2,)),
             carry_outputs=(1,),
-            span_kind="prefill-chunk",
         )
 
         # host-side per-slot state mirrors (pushed each decode step)
@@ -740,33 +723,45 @@ class ServeEngine:
         (a prefill — or a first chunk — each), then one fused decode step
         over every decodable slot.  Returns the streamed events —
         ``Token`` per generated token, ``Completion`` per finished request
-        — in generation order."""
+        — in generation order.
+
+        With an enabled tracer each part runs in a live ``serve.*`` span
+        (and so a profiler annotation) inside ``serve.step``: ``chunk``,
+        ``admit``, ``prefill``, ``insert``, ``first_token``, ``pages``,
+        ``decode``, ``decode_wait``, ``emit``, ``kv_health``."""
         if not self.scheduler.has_work:
             return []
         self._steps += 1
-        events: list[Token | Completion] = []
+        span = self.tracer.span
+        with span("serve.step", step=self._steps):
+            events: list[Token | Completion] = []
+            decoding = sum(
+                1 for slot in self.scheduler.active
+                if slot not in self._prefilling
+            )
+            planned, reserved = self._plan_chunks(decoding)
+            spent = decoding + sum(run for _, run in planned) + reserved
+            for slot, run in planned:
+                with span("serve.chunk", slot=slot, tokens=run):
+                    self._run_chunk(slot, run, events)
 
-        decoding = sum(
-            1 for slot in self.scheduler.active if slot not in self._prefilling
-        )
-        planned, reserved = self._plan_chunks(decoding)
-        spent = decoding + sum(run for _, run in planned) + reserved
-        for slot, run in planned:
-            self._run_chunk(slot, run, events)
-
-        admitted = self.scheduler.admissions(spent=spent)
-        # concurrency peaks right after admission, before same-step
-        # finishes release their slots — sample it here, not at step end
-        self._max_active = max(self._max_active, len(self.scheduler.active))
-        for state in admitted:
-            events.extend(self._admit(state))
-        if any(
-            slot not in self._prefilling for slot in self.scheduler.active
-        ):
-            events.extend(self._decode_active())
-        self._sample_kv_health()
-        self._queue_depth_g.set(len(self.scheduler.waiting))
-        self._active_slots_g.set(len(self.scheduler.active))
+            with span("serve.admit"):
+                admitted = self.scheduler.admissions(spent=spent)
+            # concurrency peaks right after admission, before same-step
+            # finishes release their slots — sample it here, not at step end
+            self._max_active = max(
+                self._max_active, len(self.scheduler.active)
+            )
+            for state in admitted:
+                events.extend(self._admit(state))
+            if any(
+                slot not in self._prefilling for slot in self.scheduler.active
+            ):
+                events.extend(self._decode_active())
+            with span("serve.kv_health"):
+                self._sample_kv_health()
+                self._queue_depth_g.set(len(self.scheduler.waiting))
+                self._active_slots_g.set(len(self.scheduler.active))
         return events
 
     def run_until_idle(self, max_steps: int | None = None) -> list[Completion]:
@@ -977,9 +972,7 @@ class ServeEngine:
         envelope (default: probe the live device) — the serve-side
         analogue of the paper's FPGA resource-fit pre-check.  The plan's
         pool-token figure is cross-checked against the live ``PagePool``
-        so the static math can never drift from the engine's accounting,
-        and fit/headroom land on the metrics registry for the re-planner
-        to watch."""
+        so the static math can never drift from the engine's accounting."""
         from repro.analysis.resources import plan_serve_capacity
 
         plan = plan_serve_capacity(
@@ -995,9 +988,6 @@ class ServeEngine:
                 f"capacity plan sized the pool at {plan.pool_tokens} tokens "
                 f"but the live PagePool holds {self.kv.pool.token_capacity}"
             )
-        self._capacity_fits_g.set(1.0 if plan.fits else 0.0)
-        self._capacity_headroom_g.set(float(plan.headroom_bytes))
-        self._capacity_max_slots_g.set(float(plan.max_slots))
         return plan
 
     # -- phase execution -------------------------------------------------------
@@ -1161,21 +1151,24 @@ class ServeEngine:
                 state, context, self._fresh_b1_cache()
             )
             events: list[Token | Completion] = []
-            self._run_chunk(state.slot, self.prefill_chunk, events)
+            with self.tracer.span("serve.chunk", slot=state.slot,
+                                  tokens=self.prefill_chunk):
+                self._run_chunk(state.slot, self.prefill_chunk, events)
             return events
 
         temp, topk = self._request_knobs(state)
-        tokens = self._padded_prompt(context)
         with self._phase("prefill"), meter_window(self.meter) as tele:
-            tok, b1_cache = self._prefill_fn(
-                self.params,
-                jnp.asarray(tokens),
-                jnp.asarray(len(context) - 1, jnp.int32),
-                jnp.asarray(state.seed, jnp.int32),
-                jnp.asarray(len(state.tokens), jnp.int32),
-                jnp.asarray(temp, jnp.float32),
-                jnp.asarray(topk, jnp.int32),
-            )
+            with self.tracer.span("serve.prefill", tokens=len(context)):
+                tokens = self._padded_prompt(context)
+                tok, b1_cache = self._prefill_fn(
+                    self.params,
+                    jnp.asarray(tokens),
+                    jnp.asarray(len(context) - 1, jnp.int32),
+                    jnp.asarray(state.seed, jnp.int32),
+                    jnp.asarray(len(state.tokens), jnp.int32),
+                    jnp.asarray(temp, jnp.float32),
+                    jnp.asarray(topk, jnp.int32),
+                )
             events = []
             self._commit_slot(state, tok, b1_cache, events)
         self.telemetry["prefill"].add(tele, len(context))
@@ -1192,16 +1185,19 @@ class ServeEngine:
         sampled token and arm the slot for decode."""
         slot = state.slot
         context = self._ctx_len(state)
-        if self.paged:
-            # pad the b1 cache's sequence up to whole pages so the insert
-            # scatters complete pages (prefill already built it that long)
-            page_row = self._slot_page_row(slot)
-        else:
-            page_row = jnp.zeros((1,), jnp.int32)  # unused operand
-        self.cache = self._insert_fn(
-            self.cache, b1_cache, jnp.asarray(slot, jnp.int32), page_row
-        )
-        first = int(np.asarray(tok)[0])  # blocks inside the meter window
+        with self.tracer.span("serve.insert", slot=slot):
+            if self.paged:
+                # pad the b1 cache's sequence up to whole pages so the
+                # insert scatters complete pages (prefill already built
+                # it that long)
+                page_row = self._slot_page_row(slot)
+            else:
+                page_row = jnp.zeros((1,), jnp.int32)  # unused operand
+            self.cache = self._insert_fn(
+                self.cache, b1_cache, jnp.asarray(slot, jnp.int32), page_row
+            )
+        with self.tracer.span("serve.first_token"):
+            first = int(np.asarray(tok)[0])  # blocks inside the meter window
 
         temp, topk = self._request_knobs(state)
         gen_index = len(state.tokens)
@@ -1232,81 +1228,90 @@ class ServeEngine:
             state.first_token_at = now
         state.tokens.append(first)
         events.append(
-            Token(state.request_id, first, gen_index, "prefill", self._steps)
+            Token(
+                state.request_id, first, gen_index, "prefill", self._steps,
+                now,
+            )
         )
         if state.done:
             events.append(self._finish(slot))
 
     def _decode_active(self) -> list[Token | Completion]:
-        if self.paged:
-            # grow page capacity for this step's writes up front; under
-            # pool pressure this preempts the youngest request (which may
-            # shrink the decoding set)
-            for slot in sorted(self.scheduler.active):
-                if slot in self._prefilling:
-                    continue
-                if slot not in self.scheduler.active:
-                    continue  # preempted by an earlier slot's ensure
-                self._ensure_pages(slot, int(self._lengths[slot]) + 1)
-        active = {
-            slot: state
-            for slot, state in self.scheduler.active.items()
-            if slot not in self._prefilling
-        }
-        if not active:
-            return []
-        if self.kv is None:
-            pages = jnp.zeros((1,), jnp.int32)  # unused operand
-        else:
-            if self._pages_version != self.kv.version:
-                self._pages_op = jnp.asarray(self.kv.array())
-                self._pages_version = self.kv.version
-            pages = self._pages_op
+        span = self.tracer.span
+        with span("serve.pages"):
+            if self.paged:
+                # grow page capacity for this step's writes up front; under
+                # pool pressure this preempts the youngest request (which
+                # may shrink the decoding set)
+                for slot in sorted(self.scheduler.active):
+                    if slot in self._prefilling:
+                        continue
+                    if slot not in self.scheduler.active:
+                        continue  # preempted by an earlier slot's ensure
+                    self._ensure_pages(slot, int(self._lengths[slot]) + 1)
+            active = {
+                slot: state
+                for slot, state in self.scheduler.active.items()
+                if slot not in self._prefilling
+            }
+            if not active:
+                return []
+            if self.kv is None:
+                pages = jnp.zeros((1,), jnp.int32)  # unused operand
+            else:
+                if self._pages_version != self.kv.version:
+                    self._pages_op = jnp.asarray(self.kv.array())
+                    self._pages_version = self.kv.version
+                pages = self._pages_op
         t0 = time.perf_counter()
         self.monitor.start()
         with self._phase("decode"), meter_window(self.meter) as tele:
-            tok, self.cache = self._decode_fn(
-                self.params,
-                jnp.asarray(self._last_tok),
-                self.cache,
-                pages,
-                jnp.asarray(self._seeds),
-                jnp.asarray(self._gen_counts),
-                jnp.asarray(self._temps),
-                jnp.asarray(self._topks),
-            )
-            toks = np.asarray(tok)  # the only device->host transfer: (B,)
+            with span("serve.decode", batch=len(active), step=self._steps):
+                tok, self.cache = self._decode_fn(
+                    self.params,
+                    jnp.asarray(self._last_tok),
+                    self.cache,
+                    pages,
+                    jnp.asarray(self._seeds),
+                    jnp.asarray(self._gen_counts),
+                    jnp.asarray(self._temps),
+                    jnp.asarray(self._topks),
+                )
+            with span("serve.decode_wait"):
+                # the only device->host transfer: (B,) token ids
+                toks = np.asarray(tok)
+            at = time.perf_counter()
         self.monitor.stop(self._steps)
         self.telemetry["decode"].add(tele, len(active))
         if self.tracer.enabled:
-            t1 = time.perf_counter()
-            # one fused-step span on the engine track, mirrored onto each
-            # participating request's track so per-request timelines show
-            # their decode cadence (and the gaps where they waited)
-            self.tracer.add_span(
-                "decode", t0, t1, batch=len(active), step=self._steps,
-            )
+            # the fused step mirrored onto each participating request's
+            # track, so per-request timelines show their decode cadence
+            # (and the gaps where they waited)
             for state in active.values():
                 self.tracer.add_span(
-                    "decode", t0, t1, tid=request_track(state.request_id),
+                    "decode", t0, at, tid=request_track(state.request_id),
                     request=state.request_id, step=self._steps,
                 )
 
         events: list[Token | Completion] = []
-        for slot, state in active.items():
-            token = int(toks[slot])
-            self._last_tok[slot, 0] = token
-            self._gen_counts[slot] += 1
-            # kv.lengths needs no sync: _ensure_pages set it to this very
-            # value before the step ran
-            self._lengths[slot] += 1
-            index = len(state.tokens)
-            state.tokens.append(token)
-            events.append(
-                Token(state.request_id, token, index, "decode", self._steps)
-            )
-            if state.done:
-                events.append(self._finish(slot))
+        with span("serve.emit"):
+            for slot, state in active.items():
+                token = int(toks[slot])
+                self._last_tok[slot, 0] = token
+                self._gen_counts[slot] += 1
+                # kv.lengths needs no sync: _ensure_pages set it to this
+                # very value before the step ran
+                self._lengths[slot] += 1
+                index = len(state.tokens)
+                state.tokens.append(token)
+                events.append(
+                    Token(
+                        state.request_id, token, index, "decode",
+                        self._steps, at,
+                    )
+                )
+                if state.done:
+                    events.append(self._finish(slot))
         return events
 
     def _finish(self, slot: int) -> Completion:

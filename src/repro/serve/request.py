@@ -58,6 +58,9 @@ class Token:
     index: int  # position in the generated sequence (0 = first new token)
     phase: str  # "prefill" (the token sampled off the prompt) | "decode"
     engine_step: int  # engine step() call that produced it
+    #: ``time.perf_counter()`` when its id was read on the host: the caller
+    #: sees it only when ``step()`` returns, after the rest of the step
+    at: float
 
 
 @dataclasses.dataclass(frozen=True)

@@ -91,6 +91,7 @@ def _slot_key(seed: jax.Array, step: jax.Array) -> jax.Array:
     return jax.random.fold_in(jax.random.fold_in(base, seed), step)
 
 
+@jax.named_scope("sample")
 def sample_tokens(
     logits: jax.Array,  # (B, V) float
     seeds: jax.Array,  # (B,) int32: per-request sampling seed

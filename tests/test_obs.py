@@ -8,6 +8,7 @@ the same observations — parity between views is asserted, not hoped for.
 """
 
 import json
+import re
 import threading
 import time
 import urllib.request
@@ -148,18 +149,6 @@ def test_chrome_export_is_viewer_valid(tmp_path):
     assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in spans)
     # spans sorted by start time, timestamps in µs relative to the epoch
     assert [e["ts"] for e in spans] == sorted(e["ts"] for e in spans)
-
-
-def test_jsonl_round_trip(tmp_path):
-    tr = Tracer()
-    with tr.span("a"):
-        pass
-    tr.event("b")
-    path = tmp_path / "trace.jsonl"
-    tr.write_jsonl(str(path))
-    events = timeline.load_events(str(path))
-    assert [e["name"] for e in events] == ["a", "b"]
-    assert timeline.validate(events) == []
 
 
 def test_timeline_cli_check(tmp_path, capsys):
@@ -427,26 +416,20 @@ def test_engine_ttft_admitted_and_queue_wait(traced_engine):
         assert c.ttft == pytest.approx(c.queue_wait + c.ttft_admitted)
 
 
-def test_engine_program_stats_and_no_span_lint(traced_engine):
+def test_engine_program_stats(traced_engine):
     engine, _ = traced_engine
     stats = engine.programs.stats()
     assert stats["decode"]["calls"] > 0
     assert stats["decode"]["retraces"] == 0
     assert stats["decode"]["compile_seconds"] > 0
-    assert stats["decode"]["span_kind"] == "decode"
-    # every engine-registered program carries a span kind, so the obs info
-    # lint stays quiet on the engine itself
-    assert not [d for d in engine.lint() if d.code == "no-span"]
-    # ...but a traced ProgramSet with an uninstrumented program is flagged
-    from repro.analysis.hotpath import ProgramSet
-
-    ps = ProgramSet()
-    ps.tracer = engine.tracer
-    ps.register("orphan", lambda x: x)
-    ps.observe("orphan", 1)
-    diags = ps.lint()
-    assert [d.code for d in diags] == ["no-span"]
-    assert diags[0].severity == "info"
+    # each first call per signature ran inside a live compile span
+    compiles = [
+        r for r in engine.tracer.records() if r.name == "serve.compile"
+    ]
+    assert sorted({r.args["program"] for r in compiles}) == sorted(
+        name for name, st in stats.items() if st["calls"]
+    )
+    assert len(compiles) == sum(st["signatures"] for st in stats.values())
 
 
 def test_engine_reset_stats_clears_obs_state():
@@ -489,3 +472,255 @@ def test_engine_disabled_tracer_records_nothing():
     assert len(engine.tracer) == 0
     # metrics still work — the registry is independent of tracing
     assert engine.registry.get("serve_requests_completed_total").value == 1
+
+
+# -- the profiler bridge ------------------------------------------------------
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """``jax.profiler.TraceAnnotation`` replaced by a recorder of its
+    enters and exits."""
+    import jax.profiler
+
+    log = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+            log.append(("new", name))
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return log
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["enabled", "disabled"])
+def test_profiler_bridge_follows_enabled(annotations, enabled):
+    tr = Tracer(enabled=enabled)
+    with tr.span("outer"):
+        with tr.span("inner", step=1):
+            pass
+    # retroactive records and instants stay on the ring only
+    tr.add_span("retro", 0.0, 1.0)
+    tr.event("instant")
+    if not enabled:
+        assert annotations == [] and len(tr) == 0
+        return
+    assert annotations == [
+        ("new", "outer"), ("enter", "outer"),
+        ("new", "inner"), ("enter", "inner"),
+        ("exit", "inner"), ("exit", "outer"),
+    ]
+    assert [r.name for r in tr.records()] == [
+        "inner", "outer", "retro", "instant"
+    ]
+
+
+def test_profiler_bridge_exits_on_error(annotations):
+    tr = Tracer()
+    with pytest.raises(KeyError):
+        with tr.span("failing"):
+            raise KeyError("x")
+    assert annotations[-1] == ("exit", "failing")
+    assert [r.name for r in tr.records()] == ["failing"]
+
+
+# -- engine spans, token read times and block scopes --------------------------
+
+#: the scopes the serving programs open besides the function blocks
+PROGRAM_SCOPES = ("kv_write", "head", "mlp", "sample")
+
+
+@pytest.fixture(scope="module")
+def paged_traced_engine():
+    """A paged engine with chunked prefill under an enabled tracer, each
+    step timed by the caller: [(call time, return time, events)]."""
+    from repro.configs import get_config
+    from repro.serve import Request, ServeEngine
+
+    cfg = get_config("llama3.2-1b").reduced()
+    engine = ServeEngine(
+        cfg, n_slots=2, max_len=64, page_size=8, prefill_chunk=16, seed=0,
+        tracer=Tracer(),
+    )
+    for prompt in ([1, 2, 3, 4, 5], list(range(1, 21)), [7, 8, 9]):
+        engine.submit(Request(prompt, max_new_tokens=6))
+    steps = []
+    while engine.scheduler.has_work:
+        t_call = time.perf_counter()
+        events = engine.step()
+        steps.append((t_call, time.perf_counter(), events))
+    return engine, steps
+
+
+def _serve_spans(engine, name):
+    return [r for r in engine.tracer.records() if r.name == name]
+
+
+@pytest.mark.parametrize("name,count", [
+    ("serve.step", lambda e, ev: e.stats.steps),
+    ("serve.admit", lambda e, ev: e.stats.steps),
+    ("serve.kv_health", lambda e, ev: e.stats.steps),
+    ("serve.decode", lambda e, ev: e.telemetry["decode"].calls),
+    ("serve.decode_wait", lambda e, ev: e.telemetry["decode"].calls),
+    ("serve.emit", lambda e, ev: e.telemetry["decode"].calls),
+    ("serve.chunk", lambda e, ev: e.stats.prefill_chunks),
+    # one unchunked prompt each: the 5- and 3-token requests
+    ("serve.prefill", lambda e, ev: 2),
+    # one insert and one blocking first-token read per first token
+    ("serve.insert", lambda e, ev: sum(
+        1 for x in ev if getattr(x, "phase", None) == "prefill")),
+    ("serve.first_token", lambda e, ev: sum(
+        1 for x in ev if getattr(x, "phase", None) == "prefill")),
+])
+def test_engine_serve_span_counts(paged_traced_engine, name, count):
+    engine, steps = paged_traced_engine
+    events = [ev for _, _, evs in steps for ev in evs]
+    assert engine.stats.prefill_chunks == 2  # the 20-token prompt
+    assert len(_serve_spans(engine, name)) == count(engine, events) > 0
+
+
+def test_engine_serve_spans_nest_in_steps(paged_traced_engine):
+    engine, steps = paged_traced_engine
+    records = engine.tracer.records()
+    step_spans = sorted(
+        (r for r in records if r.name == "serve.step"), key=lambda r: r.t0
+    )
+    assert [r.args["step"] for r in step_spans] == list(
+        range(1, len(steps) + 1)
+    )
+    inner = [
+        r for r in records
+        if r.name.startswith("serve.") and r.name != "serve.step"
+    ]
+    assert {r.name for r in inner} >= {
+        "serve.pages", "serve.decode", "serve.compile",
+    }
+    engine_track = step_spans[0].tid
+    for r in inner:
+        assert r.tid == engine_track
+        assert any(s.t0 <= r.t0 and r.t1 <= s.t1 for s in step_spans), r.name
+    # each step's decode dispatch precedes its blocking read
+    decodes = sorted(_serve_spans(engine, "serve.decode"), key=lambda r: r.t0)
+    waits = sorted(
+        _serve_spans(engine, "serve.decode_wait"), key=lambda r: r.t0
+    )
+    assert all(d.t1 <= w.t0 for d, w in zip(decodes, waits))
+    # the engine track carries no retroactive decode span any more; each
+    # request's track keeps its mirrored one
+    retro = [r for r in records if r.name == "decode"]
+    assert retro and all(r.tid != engine_track for r in retro)
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["traced", "untraced"])
+def test_token_read_times(paged_traced_engine, traced):
+    from repro.configs import get_config
+    from repro.serve import Request, ServeEngine, Token
+
+    if traced:
+        _, steps = paged_traced_engine
+    else:
+        cfg = get_config("llama3.2-1b").reduced()
+        engine = ServeEngine(cfg, n_slots=2, max_len=64, page_size=8, seed=0)
+        for prompt in ([1, 2, 3], [4, 5, 6, 7]):
+            engine.submit(Request(prompt, max_new_tokens=3))
+        steps = []
+        while engine.scheduler.has_work:
+            t_call = time.perf_counter()
+            events = engine.step()
+            steps.append((t_call, time.perf_counter(), events))
+    last = 0.0
+    for t_call, t_return, events in steps:
+        for tok in (e for e in events if isinstance(e, Token)):
+            assert t_call <= tok.at <= t_return
+            assert tok.at >= last
+            last = tok.at
+
+
+#: instructions that run no device operation of their own
+FREE = re.compile(r" (constant|parameter|get-tuple-element|tuple|bitcast)\(")
+
+
+def _computations(hlo_text):
+    """computation name -> its instruction lines."""
+    comps, current = {}, None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            current = line.split()[1 if line.startswith("ENTRY") else 0]
+            comps[current.lstrip("%")] = []
+        elif current and line.startswith("  "):
+            comps[current.lstrip("%")].append(line)
+    return comps
+
+
+def test_op_scopes_on_the_paged_decode_program(paged_traced_engine):
+    from repro.core import blocks
+    from repro.obs import op_scopes
+
+    engine, _ = paged_traced_engine
+    texts = engine.programs.compiled_texts(["decode"])
+    (text,) = texts["jit_decode_fn"]
+    scopes = op_scopes(text, blocks.registry.blocks() + list(PROGRAM_SCOPES))
+    assert set(PROGRAM_SCOPES) | {"paged_attention", "rmsnorm"} <= set(
+        scopes.values()
+    )
+    comps = _computations(text)
+    walks = []
+    for line in (x for lines in comps.values() for x in lines):
+        m = re.search(r"%([\w.\-]+) = .* while\(.*body=%([\w.\-]+)", line)
+        if m and scopes[m.group(1)] == "paged_attention":
+            walks.append(m.group(2))
+    assert len(walks) >= 2  # the K and the V page walks
+    for body in walks:
+        # constants and tuple plumbing carry whichever op XLA merged or
+        # widened them from; they take no device time
+        named = [
+            line for line in comps[body]
+            if "op_name=" in line and not FREE.search(line)
+        ]
+        assert named
+        for line in named:
+            name = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)", line).group(1)
+            assert scopes[name] == "paged_attention", line
+
+
+def test_compiled_texts_one_per_signature(paged_traced_engine):
+    engine, _ = paged_traced_engine
+    stats = engine.programs.stats()
+    texts = engine.programs.compiled_texts()
+    assert sum(len(t) for t in texts.values()) == sum(
+        st["signatures"] for st in stats.values()
+    )
+    assert set(texts) >= {"jit_decode_fn", "jit_extend_fn"}
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(f)/while/body/paged_attention/while/body/gather", "paged_attention"),
+    ("jit(f)/head/rmsnorm/mul", "rmsnorm"),  # the innermost scope wins
+    ("jit(f)/head/dot_general", "head"),
+    ("jit(f)/while/body/add", "other"),
+    ("jit(f)/paged_attention_extra/add", "other"),  # whole components only
+    (None, "other"),
+])
+def test_op_scopes_innermost_name(op_name, scope):
+    from repro.obs import module_name, op_scopes
+
+    meta = f', metadata={{op_name="{op_name}" stack_frame_id=3}}' if op_name else ""
+    text = (
+        "HloModule jit_f, entry_computation_layout={()->f32[]}\n\n"
+        "ENTRY %main.1 () -> f32[] {\n"
+        f"  ROOT %fusion.7 = f32[] fusion(), kind=kLoop{meta}\n"
+        "}\n"
+    )
+    assert module_name(text) == "jit_f"
+    assert op_scopes(text, ["paged_attention", "head", "rmsnorm"]) == {
+        "fusion.7": scope
+    }

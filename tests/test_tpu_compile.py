@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -144,3 +145,76 @@ def test_fft2d_compiles(one_chip, n):
 def test_lu_compiles(one_chip, n):
     fn = functools.partial(ops.lu, backend="pallas")
     _compile(fn, one_chip, ((n, n), F32))
+
+
+#: instructions that run no device operation of their own
+FREE = re.compile(r" (constant|parameter|get-tuple-element|tuple|bitcast)\(")
+
+
+def _decode_program_text(one_chip, cfg, slots, max_len, page):
+    """The serving engine's paged decode program
+    (``ServeEngine._build_decode``, the XLA page walk), compiled for the
+    described chip."""
+    import types
+
+    from repro.models import lm
+    from repro.models import params as pm
+    from repro.serve.engine import ServeEngine
+
+    n_pages = slots * (max_len // page) + 1
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(struct, pm.abstract_params(lm.build_metas(cfg)))
+    cache = jax.tree.map(struct, pm.abstract_params(lm.cache_metas_tree(
+        cfg, slots, max_len, page_size=page, n_pages=n_pages,
+    )))
+    i32 = jnp.int32
+    args = [
+        params, jax.ShapeDtypeStruct((slots, 1), i32, sharding=one_chip),
+        cache,
+        jax.ShapeDtypeStruct((slots, max_len // page), i32, sharding=one_chip),
+        *(jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
+          for dt in (i32, i32, F32, i32)),
+    ]
+    decode_fn = ServeEngine._build_decode(
+        types.SimpleNamespace(cfg=cfg, paged=True)
+    )
+    return jax.jit(decode_fn, donate_argnums=(2,)).lower(*args).compile().as_text()
+
+
+def test_paged_decode_page_walk_in_its_block_scope(one_chip):
+    """On the chip's compiler too, the page-walk loops of the decode
+    program (and every named instruction of their bodies) land in the
+    ``paged_attention`` scope, the KV write in ``kv_write``."""
+    from repro.configs import get_config
+    from repro.core import blocks
+    from repro.obs import module_name, op_scopes
+
+    text = _decode_program_text(
+        one_chip, get_config("llama3.2-1b").reduced(), SLOTS, 128, PAGE
+    )
+    assert module_name(text) == "jit_decode_fn"
+    scopes = op_scopes(
+        text, blocks.registry.blocks() + ["kv_write", "head", "mlp", "sample"]
+    )
+    bodies, lines = {}, {}
+    current = None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            current = line.split()[1 if line.startswith("ENTRY") else 0]
+            lines[current.lstrip("%")] = []
+        elif current and line.startswith("  "):
+            lines[current.lstrip("%")].append(line)
+            m = re.search(r"%([\w.\-]+) = .* while\(.*body=%([\w.\-]+)", line)
+            if m:
+                bodies[m.group(1)] = m.group(2)
+    walks = [w for w in bodies if scopes[w] == "paged_attention"]
+    assert len(walks) >= 2  # the K and the V walk
+    for w in walks:
+        for line in lines[bodies[w]]:
+            if "op_name=" in line and not FREE.search(line):
+                name = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)", line).group(1)
+                assert scopes[name] == "paged_attention", line
+    assert {"kv_write", "head", "sample", "mlp"} <= set(scopes.values())
