@@ -37,7 +37,7 @@ serve-bench:
 serve-bench-paged:
 	PYTHONPATH=src$(if $(PYTHONPATH),:$(PYTHONPATH)) $(PYTHON) benchmarks/serve_load.py --fast --meter auto --page-size 16 --prefill-chunk 8 --json-out BENCH_serve_paged.json
 
-# Paged-attention microbench: fused page walk vs gathered view across
+# Paged-attention microbench: fused page walk vs the XLA block walk across
 # page sizes — measured latency where the kernel can run, static
 # peak-live-bytes everywhere.  Snapshot lands in BENCH_paged_attn.json.
 .PHONY: bench-paged-attn

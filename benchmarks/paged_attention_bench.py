@@ -1,9 +1,9 @@
-"""Paged-attention microbenchmark: fused page walk vs gathered view.
+"""Paged-attention microbenchmark: fused page walk vs the XLA block walk.
 
 Sweeps page sizes at a fixed decode shape and reports, per
 ``(page_size, impl)`` cell, the measured step latency and the static
 memory envelope (``repro.analysis.resources.estimate_memory``) of a
-jitted single-block decode call.  The gather (XLA) path is always timed
+jitted single-block decode call.  The XLA walk is always timed
 on the local backend; the fused Pallas kernel is timed only where it can
 actually run — on a TPU, or in interpret mode when ``--interpret`` is
 passed (orders of magnitude slower; parity checking only, not a

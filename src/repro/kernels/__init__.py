@@ -12,6 +12,7 @@ from repro.analysis.legality import TargetConstraints
 from repro.analysis.resources import ResourceHint
 from repro.core import blocks
 from repro.kernels import attention_xla, ops, ref  # noqa: F401
+from repro.kernels import paged_attention as _paged
 
 
 def _register_all() -> list[tuple[str, str, object]]:
@@ -33,7 +34,7 @@ def _register_all() -> list[tuple[str, str, object]]:
         # paged attention (the serving decode/extend hot loop)
         ("paged_attention", "xla",
          functools.partial(ops.paged_attention, backend="xla"),
-         "rolled page-walk gather + dense masked softmax"),
+         "rolled walk over the live page blocks, online softmax"),
         ("paged_attention", "pallas",
          functools.partial(ops.paged_attention, backend="pallas"),
          "fused page-walk flash attention (no gathered K/V view)"),
@@ -153,12 +154,13 @@ def _resource_metadata() -> dict[tuple[str, str], ResourceHint]:
         vmem_tile_bytes=5 * tile * tile * f32,
         notes="q tile + streamed k/v tiles + acc + running stats",
     )
-    # xla paged target: the page walk materialises the gathered
-    # (B, max_pages * page_size) K/V view — roughly one extra cache-sized
-    # copy per K/V leaf live in the decode program
+    # xla paged target: each trip of the walk holds one block of pages
+    # per slot for K and for V, at the pool dtype and as float32; the
+    # block is a fixed byte size, whatever max_pages is
     out[("paged_attention", "xla")] = ResourceHint(
-        memory_multiplier=1.5,
-        notes="gathered per-slot K/V view materialised per decode step",
+        workspace_bytes=2 * 3 * _paged.BLOCK_BYTES,
+        notes="per slot: one K and one V block of pages at the pool dtype "
+              "plus their float32 copies; independent of max_pages",
     )
     # fused kernel: NO gather multiplier — the working set is the q rows
     # plus one (page_size, head_dim) block per K/V operand plus the

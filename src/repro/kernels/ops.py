@@ -167,8 +167,8 @@ def paged_attention(
 ):
     """Paged decode/extend attention through the page table.
 
-    pallas: the fused page-walk kernel (no gathered K/V view); xla: the
-    rolled gather + dense masked softmax.  When the pallas target is
+    pallas: the fused page-walk kernel; xla: a rolled walk over the live
+    blocks of pages with an online softmax.  When the pallas target is
     *forced* off-TPU (``backend="pallas"`` on this CPU container, e.g. a
     serve run with ``--decode-impl pallas``), ``interpret`` defaults on so
     the kernel body runs in Python — the parity path CPU CI proves

@@ -2,25 +2,24 @@
 
 The serving hot loop reads K/V through a page table: slot ``b``'s logical
 position ``t`` lives in pool page ``pages[b, t // page_size]`` at row
-``t % page_size`` (entries past the allocation point at the shared *null
-page*, whose garbage rows the validity mask ``t <= index`` always hides).
+``t % page_size``.  Entries past a slot's allocation point at the shared
+*null page*, the pool's last page (``PagePool.null_page == n_pages``).
 This module owns both shelf implementations of that read:
 
-* :func:`paged_attention_xla` — the scatter-then-gather formulation: a
-  *rolled* ``fori_loop`` page walk (:func:`gather_kv_pages`) materialises
-  a contiguous ``(B, ..., max_pages * page_size, ...)`` view per K/V leaf,
-  then dense masked softmax.  Peak live bytes ~= gathered view + one page
-  block per leaf (the old advanced-index gather + ``moveaxis`` kept two
-  full copies of the view live).
+* :func:`paged_attention_xla` — a rolled walk over *blocks* of pages
+  (:func:`block_tokens` tokens each).  Each trip gathers one block of
+  pages per slot with a single indexed read of each pool, scores it, and
+  folds it into a running max, sum and weighted sum (flash decoding, the
+  algebra of the Pallas kernel).  The trip count is computed on the
+  device from the kernel's own inputs (:func:`walk_blocks`): the batch's
+  highest live block, so the walk covers the longest slot and not
+  ``max_pages``.  Its working set is one block per slot per operand.
 * :func:`paged_attention_pallas` — the fused kernel: a Pallas grid walks
   the page list *inside* the kernel via a scalar-prefetch index map
   (``pages[b, j]`` picks page ``j``'s pool block), accumulating
   flash-style online softmax (running max / sum / weighted accumulator in
-  VMEM scratch) across pages.  No gathered view exists at any point — the
-  working set is one ``(page_size, head_dim)`` block per operand — which
-  is why its ``BLOCK_RESOURCES`` hint carries *no* gather multiplier and
-  the resources pass scores the fused decode program strictly below the
-  gather path.
+  VMEM scratch) across pages.  Its working set is one
+  ``(page_size, head_dim)`` block per operand, VMEM-resident.
 
 Both support decode (S=1) and ``extend`` (S>=1 chunked prefill, causal
 within the chunk: row ``s`` of the chunk attends positions
@@ -31,9 +30,10 @@ structurally GQA with one KV head whose "keys" are the latent cache
 
     scores = (q_abs . c  +  q_rope . k_rope) * scale,  out = probs . c
 
-The page-walk loop stays *rolled* (``fori_loop`` on the XLA side, the
-grid's page axis on the Pallas side) so the traced program size is
-independent of ``max_pages`` — see SNIPPETS.md on loop primitives.
+The page walk stays *rolled* (a ``fori_loop`` with a device-computed
+bound on the XLA side, the grid's page axis on the Pallas side) so the
+traced program size is independent of ``max_pages`` — see SNIPPETS.md on
+loop primitives.
 
 Pool layouts (as produced by ``repro.models.attention.cache_metas_paged``):
 GQA ``(P_total, KH, page_size, D)``; MLA latent ``(P_total, page_size, r)``
@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -53,37 +54,6 @@ _NEG = -1e30
 # -- page-table plumbing (shared by both targets and the serve engine) ---------
 # the writes run under the ``kv_write`` scope, apart from the
 # ``paged_attention`` block's read: a profile can tell the two apart
-
-
-def gather_kv_pages(
-    pool: jax.Array, pages: jax.Array, seq_axis: int
-) -> jax.Array:
-    """Gather a per-slot contiguous K/V view from the page pool.
-
-    ``pool`` (P_total, ..., page_size @ seq_axis, ...), ``pages``
-    (B, max_pages) -> (B, ..., max_pages * page_size @ seq_axis, ...).
-
-    The walk is a rolled ``fori_loop`` writing one page block per step
-    into a preallocated view — the traced program holds the view plus a
-    single ``(B, ..., page_size, ...)`` block, instead of the advanced-
-    index gather + ``moveaxis`` pair that kept two full copies of the
-    gathered view live.
-    """
-    b, mp = pages.shape
-    ps = pool.shape[seq_axis]
-    if mp == 1:  # a single page IS the view; no walk to roll
-        return pool[pages[:, 0]]
-    out_shape = (
-        (b,) + pool.shape[1:seq_axis] + (mp * ps,) + pool.shape[seq_axis + 1 :]
-    )
-
-    def walk(j, acc):
-        blk = pool[pages[:, j]]  # (B, ..., page_size @ seq_axis, ...)
-        return jax.lax.dynamic_update_slice_in_dim(
-            acc, blk, j * ps, axis=seq_axis
-        )
-
-    return jax.lax.fori_loop(0, mp, walk, jnp.zeros(out_shape, pool.dtype))
 
 
 @jax.named_scope("kv_write")
@@ -157,7 +127,49 @@ def insert_pages(
     return pool.at[:, page_ids].set(x.astype(pool.dtype))
 
 
-# -- the XLA target: rolled gather, then dense masked softmax ------------------
+# -- the XLA target: a walk over the live page blocks, online softmax ---------
+
+#: bytes of one slot's K rows in one block of the walk: each trip gathers
+#: this much K (and the matching V) per slot — 128 tokens of a 32-head,
+#: 64-wide bfloat16 pool, 256 of an 8-head, 128-wide one.  Halving or
+#: doubling it was slower on a TPU v5e at both of those shapes
+BLOCK_BYTES = 512 << 10
+
+
+def block_tokens(page_size: int, token_bytes: int, max_pages: int) -> int:
+    """Tokens per block of the walk: whole pages whose K rows of one slot
+    (``token_bytes`` per token, all KV heads) come to ``BLOCK_BYTES``; at
+    least one page, at most ``max_pages``."""
+    pages = BLOCK_BYTES // (page_size * token_bytes)
+    return max(1, min(max_pages, pages)) * page_size
+
+
+def walk_blocks(index, pages, s: int, *, page_size: int, block: int,
+                null_page: int, xp=np):
+    """Per-slot blocks the walk must visit: ``ceil((index + s) / block)``,
+    capped by the blocks of the slot's allocated prefix (its last
+    non-null table entry).  The walk makes ``max`` of these trips.
+
+    One function for both sides: the kernel calls it with ``xp=jnp`` on
+    its traced operands, the serve engine with ``xp=np`` on its host
+    mirrors, so the engine's walk counters count the trips the device
+    makes.  A row of null pages (an idle slot) needs 0 blocks however
+    stale its ``index``.
+    """
+    col = xp.arange(1, pages.shape[1] + 1)
+    allocated = xp.where(pages != null_page, col, 0).max(axis=1)
+    ppb = block // page_size
+    want = (index + s + block - 1) // block
+    return xp.minimum(want, (allocated + ppb - 1) // ppb)
+
+
+def walk_plan(k_pool: jax.Array, pages: jax.Array, index: jax.Array, s: int):
+    """(tokens per block, per-slot blocks) of the walk over these kernel
+    operands; the walk makes ``max`` of the blocks trips."""
+    n_total, kh, ps, d = k_pool.shape
+    bt = block_tokens(ps, kh * d * k_pool.dtype.itemsize, pages.shape[1])
+    return bt, walk_blocks(index, pages, s, page_size=ps, block=bt,
+                           null_page=n_total - 1, xp=jnp)
 
 
 def paged_attention_xla(
@@ -171,39 +183,73 @@ def paged_attention_xla(
     kr_pool: jax.Array | None = None,  # MLA: (P_total, 1, page_size, Dr)
     scale: float | None = None,
 ) -> jax.Array:
+    """Paged attention as a rolled walk over blocks of pages.
+
+    Contract: the pool's last page (``P_total - 1``) is the null page,
+    and every table entry past a slot's allocation names it.  Trip ``j``
+    gathers table columns ``[j * ppb, (j + 1) * ppb)`` of every slot (K,
+    V and MLA's rope channel), scores them in float32 and folds them into
+    a running max, sum and weighted sum.  The walk makes
+    ``max(walk_blocks(...))`` trips; a slot attends positions
+    ``t <= index + s`` inside its own blocks, so an idle slot (a null-page
+    row) reads nothing and returns zeros, as does every slot of a batch
+    whose walk makes no trip.
+    """
     b, h, s, dk = q.shape
-    kh = k_pool.shape[1]
+    n_total, kh, ps, _ = k_pool.shape
     g = h // kh
     dv = v_pool.shape[-1]
-    k_view = gather_kv_pages(k_pool, pages, seq_axis=2)  # (B, KH, T, Dk)
-    v_view = gather_kv_pages(v_pool, pages, seq_axis=2)
-    smax = k_view.shape[2]
-    qpos = index[:, None] + jnp.arange(s)  # (B, S)
+    mp = pages.shape[1]
+    bt, blocks = walk_plan(k_pool, pages, index, s)
+    ppb = bt // ps
+    # a last block that overhangs max_pages reads the null page there
+    table = jnp.pad(pages, ((0, 0), (0, -(-mp // ppb) * ppb - mp)),
+                    constant_values=n_total - 1)
+    end = (blocks * bt)[:, None, None, None, None]  # the slot's own blocks
+    qpos = (index[:, None] + jnp.arange(s))[:, None, None, :, None]
+    qg = q.reshape(b, kh, g, s, dk).astype(jnp.float32)
     if q_rope is None:
-        # division (not multiply-by-reciprocal) to stay bit-identical with
-        # the contiguous decode path serving tests compare against
-        qg = q.reshape(b, kh, g, s, dk).astype(jnp.float32)
+        # division (not multiply-by-reciprocal), as the contiguous decode
+        # path the serving tests compare against divides
         qg = qg * scale if scale is not None else qg / (dk ** 0.5)
-        sc = jnp.einsum("bkgqd,bktd->bkgqt", qg, k_view.astype(jnp.float32))
     else:
         if scale is None:
             scale = 1.0 / (dk ** 0.5)
-        qg = q.reshape(b, kh, g, s, dk).astype(jnp.float32)
         qr = q_rope.reshape(b, kh, g, s, -1).astype(jnp.float32)
-        kr_view = gather_kv_pages(kr_pool, pages, seq_axis=2)
-        sc = (
-            jnp.einsum("bkgqd,bktd->bkgqt", qg, k_view.astype(jnp.float32))
-            + jnp.einsum(
-                "bkgqd,bktd->bkgqt", qr, kr_view.astype(jnp.float32)
-            )
-        ) * scale
-    valid = (
-        jnp.arange(smax)[None, None, None, None, :]
-        <= qpos[:, None, None, :, None]
+
+    def scores(qx, pool, ids):  # (B, KH, G, S, bt)
+        kx = pool[ids].astype(jnp.float32)  # (B, ppb, KH, ps, D)
+        sc = jnp.einsum("bkgqd,bpktd->bkgqpt", qx, kx)
+        return sc.reshape(b, kh, g, s, bt)
+
+    def trip(j, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(table, j * ppb, ppb, axis=1)
+        sc = scores(qg, k_pool, ids)
+        if q_rope is not None:
+            sc = (sc + scores(qr, kr_pool, ids)) * scale
+        t = j * bt + jnp.arange(bt)
+        valid = (t <= qpos) & (t < end)
+        sc = jnp.where(valid, sc, _NEG)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        # explicit re-mask: a row that has seen only masked positions has
+        # m_new == _NEG, where exp(sc - m_new) would read 1
+        p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        vx = v_pool[ids].astype(jnp.float32)  # (B, ppb, KH, ps, Dv)
+        pv = jnp.einsum(
+            "bkgqpt,bpktd->bkgqd", p.reshape(b, kh, g, s, ppb, ps), vx
+        )
+        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + pv)
+
+    carry = (
+        jnp.full((b, kh, g, s, 1), _NEG, jnp.float32),
+        jnp.zeros((b, kh, g, s, 1), jnp.float32),
+        jnp.zeros((b, kh, g, s, dv), jnp.float32),
     )
-    sc = jnp.where(valid, sc, _NEG)
-    p = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("bkgqt,bktd->bkgqd", p, v_view.astype(jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, jnp.max(blocks), trip, carry)
+    o = acc / jnp.where(l == 0.0, 1.0, l)
     return o.reshape(b, h, s, dv).astype(q.dtype)
 
 

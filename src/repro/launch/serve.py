@@ -180,7 +180,7 @@ def add_engine_args(ap: argparse.ArgumentParser) -> None:
                     choices=("auto", "xla", "pallas"),
                     help="pin the paged_attention binding for the decode "
                          "hot loop (requires --page-size): xla = rolled "
-                         "page-walk gather, pallas = fused page-walk "
+                         "walk over page blocks, pallas = fused page-walk "
                          "kernel (interpret-mode off-TPU); auto defers to "
                          "the stored decode plan / default preference")
     ap.add_argument("--kv-validate", action="store_true",

@@ -26,7 +26,6 @@ from repro.kernels.attention_xla import attention_chunked  # noqa: F401
 # page-table plumbing shared by both paged_attention shelf targets and the
 # serve engine's page insert; re-exported from the kernel layer
 from repro.kernels.paged_attention import (  # noqa: F401
-    gather_kv_pages,
     insert_pages,
     scatter_chunk_pages,
     scatter_token_pages,
@@ -221,8 +220,8 @@ def gqa_forward(
                     cache["v"], vt, pages, index, seq_axis=2
                 )
             # the attention read is a planner-searchable function block:
-            # xla = rolled page-walk gather + dense softmax, pallas = the
-            # fused page-walk kernel (no gathered view)
+            # xla = a walk over the live page blocks with an online
+            # softmax, pallas = the fused page-walk kernel
             o = blocks.call(
                 "paged_attention", qt, k_cache, v_cache, pages, index
             )

@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 import time
 from typing import Any, Iterable, Sequence
 
@@ -61,6 +62,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import ArchConfig
 from repro.core import blocks as blocks_mod
+from repro.kernels.paged_attention import block_tokens, walk_blocks
 from repro.metering import meter_window, resolve_meter
 from repro.metering.meters import WindowTelemetry
 from repro.models import lm
@@ -344,6 +346,29 @@ class ServeEngine:
             self.cache = lm.init_cache(
                 cfg, n_slots, max_len, page_size=page_size, n_pages=n_pages
             )
+            # (layers, block tokens) of each attention group's page walk,
+            # sized as the paged_attention kernel sizes it from its K pool
+            # (MLA's latent pool is its K)
+            self._walks = []
+            for key, kind in self._group_kinds.items():
+                if kind == "m":
+                    continue
+                group = self.cache[key]
+                pool = group["k"] if "k" in group else group["c"]
+                layers, _, *page = pool.shape
+                token_bytes = math.prod(page) // page_size * pool.dtype.itemsize
+                self._walks.append(
+                    (layers, block_tokens(page_size, token_bytes, max_pages))
+                )
+            self._walk_c = self.registry.counter(
+                "serve_paged_walk_blocks_total",
+                "page blocks the decode steps' paged attention walks visit, "
+                "over every attention layer",
+            )
+            self._walk_full_c = self.registry.counter(
+                "serve_paged_walk_blocks_full_total",
+                "page blocks those walks would visit over all max_pages",
+            )
         else:
             self.kv = None
             self._slot_len = max_len
@@ -456,6 +481,9 @@ class ServeEngine:
         self._temps = np.zeros((n_slots,), np.float32)
         self._topks = np.zeros((n_slots,), np.int32)
         self._lengths = np.zeros((n_slots,), np.int64)  # resident tokens
+        # the cache's per-row write positions: idle and mid-prefill rows
+        # keep advancing with every decode step, as the device's do
+        self._dev_index = np.zeros((n_slots,), np.int64)
 
         #: slots mid-chunked-prefill (slot -> _PrefillProgress); these
         #: occupy a slot + pages but are excluded from decode until the
@@ -1209,6 +1237,7 @@ class ServeEngine:
         # kv.lengths needs no sync: alloc_slot/ensure already tracked the
         # context through admission and the chunk loop
         self._lengths[slot] = context
+        self._dev_index[slot] = context  # the inserted cache's index
         now = time.perf_counter()
         if self.tracer.enabled:
             track = request_track(state.request_id)
@@ -1263,6 +1292,7 @@ class ServeEngine:
                     self._pages_op = jnp.asarray(self.kv.array())
                     self._pages_version = self.kv.version
                 pages = self._pages_op
+                self._count_walk()
         t0 = time.perf_counter()
         self.monitor.start()
         with self._phase("decode"), meter_window(self.meter) as tele:
@@ -1281,6 +1311,7 @@ class ServeEngine:
                 # the only device->host transfer: (B,) token ids
                 toks = np.asarray(tok)
             at = time.perf_counter()
+        self._dev_index += 1
         self.monitor.stop(self._steps)
         self.telemetry["decode"].add(tele, len(active))
         if self.tracer.enabled:
@@ -1313,6 +1344,23 @@ class ServeEngine:
                 if state.done:
                     events.append(self._finish(slot))
         return events
+
+    def _count_walk(self) -> None:
+        """Count this decode step's page-walk trips, computed on the host
+        from the mirrors of the kernel's operands by the kernel's own
+        :func:`walk_blocks`, beside the trips a walk over all
+        ``max_pages`` would make."""
+        assert self.kv is not None
+        ps = self.kv.pool.page_size
+        for layers, block in self._walks:
+            walked = walk_blocks(
+                self._dev_index, self.kv.array(), 1, page_size=ps,
+                block=block, null_page=self.kv.pool.null_page,
+            )
+            self._walk_c.inc(layers * int(walked.max()))
+            self._walk_full_c.inc(
+                layers * -(-self.kv.max_pages * ps // block)
+            )
 
     def _finish(self, slot: int) -> Completion:
         state = self.scheduler.release(slot)

@@ -2,35 +2,40 @@
 search, and serve-level token identity.
 
 The parity tests run the fused kernel in interpret mode (the CPU-CI
-path) against an independent float64 dense oracle AND against the XLA
-gather-then-attend implementation, across decode (S=1) and extend (S>1)
-chunks, GQA and MLA layouts, ragged per-slot lengths, page boundaries,
-final partial pages and null-page table entries.  The integration tests
+path) and the XLA block walk against an independent float64 dense
+oracle, across decode (S=1) and extend (S>1) chunks, GQA and MLA
+layouts, ragged per-slot lengths, page boundaries, final partial pages,
+null-page table entries and idle slots.  The integration tests
 pin the acceptance criteria: both shelf targets carry legality/resource
 metadata regardless of import order, the zoo decode search prunes the
 TPU-only kernel statically on CPU while still committing a plan that
 binds the block, the fused program's peak live bytes sit strictly below
-the gather path's at serving-scale shapes, and a served greedy trace is
+the XLA walk's at serving-scale shapes, and a served greedy trace is
 token-for-token identical under ``decode_impl="pallas"``.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import paged_attention as paged
 from repro.kernels.paged_attention import (
-    gather_kv_pages,
+    block_tokens,
     paged_attention_pallas,
     paged_attention_xla,
     scatter_chunk_pages,
     scatter_token_pages,
+    walk_blocks,
+    walk_plan,
 )
 from repro.serve import Request, ServeEngine
 
@@ -190,21 +195,112 @@ def test_paged_block_call_dispatches(rng):
                                    rtol=1e-4, atol=1e-4)
 
 
-# -- page walk + scatter helpers -----------------------------------------------
+# -- the XLA target's walk over live page blocks -------------------------------
+
+#: a slot that holds no pages: a null-page row, its ``index`` left far past
+#: ``max_len`` by the decode steps it sat out
+IDLE = 10_000
+
+# (lengths, s): one batch whose walk stops short of max_pages, one whose
+# longest slot reaches into a last block that overhangs it
+WALK_CASES = {
+    "prefix": (37, IDLE, 50, IDLE, 0),
+    "overhang": (9, IDLE, 125, 3),
+}
 
 
-@pytest.mark.parametrize("mp", [1, 4])
-def test_rolled_gather_matches_advanced_indexing(mp, rng):
-    pool = jnp.asarray(
-        rng.standard_normal((2 * mp + 1, 2, 8, 16)), jnp.float32
+def _walk_case(rng, monkeypatch, name, s, layout):
+    """Operands for the walk at ``max_pages`` 16 with blocks of 3 pages (a
+    block count that does not divide ``max_pages``), idle slots given
+    null-page rows and a stale ``index``; returns (case, live rows)."""
+    lengths = [0 if n == IDLE else min(n, 128 - s) for n in WALK_CASES[name]]
+    kh, dr = (2, 0) if layout == "gqa" else (1, 16)
+    case = _paged_case(
+        rng, b=len(lengths), h=4, kh=kh, s=s, dk=32, dv=32, ps=8, mp=16,
+        lengths=lengths, dr=dr,
     )
-    pages = jnp.asarray(
-        rng.integers(0, 2 * mp + 1, (2, mp)).astype(np.int32)
+    monkeypatch.setattr(paged, "BLOCK_BYTES", 3 * 8 * kh * 32 * 4)
+    live = [i for i, n in enumerate(WALK_CASES[name]) if n != IDLE]
+    pages = np.array(case["pages"])
+    index = np.array(case["index"])
+    for i in set(range(len(lengths))) - set(live):
+        pages[i] = case["k_pool"].shape[0] - 1
+        index[i] = IDLE
+    case["pages"], case["index"] = jnp.asarray(pages), jnp.asarray(index)
+    return case, live
+
+
+@pytest.mark.parametrize("layout", ["gqa", "mla"])
+@pytest.mark.parametrize("s", [1, 3])
+@pytest.mark.parametrize("name", sorted(WALK_CASES))
+def test_block_walk_matches_oracle(name, s, layout, rng, monkeypatch):
+    case, live = _walk_case(rng, monkeypatch, name, s, layout)
+    assert block_tokens(8, case["k_pool"].shape[1] * 32 * 4, 16) == 24
+    got = np.asarray(jax.jit(paged_attention_xla)(**case))
+    np.testing.assert_allclose(got[live], _oracle(case)[live],
+                               rtol=1e-5, atol=1e-5)
+    # an idle slot reads nothing: its null-page row holds no block
+    idle = sorted(set(range(got.shape[0])) - set(live))
+    np.testing.assert_array_equal(got[idle], 0.0)
+
+
+@pytest.mark.parametrize("layout", ["gqa", "mla"])
+@pytest.mark.parametrize("s", [1, 3])
+@pytest.mark.parametrize("name", sorted(WALK_CASES))
+def test_walk_trips_match_host_mirror(name, s, layout, rng, monkeypatch):
+    """The trips the kernel's loop makes, computed on the device from its
+    own operands, equal the host count the serve engine feeds
+    ``serve_paged_walk_blocks_total`` with: ``walk_blocks`` over NumPy
+    mirrors, the block sized from the pool's page as the engine sizes it."""
+    case, live = _walk_case(rng, monkeypatch, name, s, layout)
+    pool = case["k_pool"]
+    trips = jax.jit(
+        lambda pool, pages, index: walk_plan(pool, pages, index, s)[1].max()
+    )(pool, case["pages"], case["index"])
+    _, kh, ps, d = pool.shape
+    block = block_tokens(ps, kh * d * pool.dtype.itemsize, 16)
+    host = walk_blocks(
+        np.asarray(case["index"]), np.asarray(case["pages"]), s,
+        page_size=ps, block=block, null_page=pool.shape[0] - 1,
     )
-    got = gather_kv_pages(pool, pages, seq_axis=2)
-    want = np.moveaxis(np.asarray(pool)[np.asarray(pages)], 2, 1)
-    want = want.reshape(2, 2, mp * 8, 16)
-    np.testing.assert_array_equal(np.asarray(got), want)
+    assert int(trips) == int(host.max())
+    longest = max(int(case["index"][i]) for i in live) + s
+    assert int(host.max()) == -(-longest // block)
+    assert host[[i for i in range(len(host)) if i not in live]].max() == 0
+
+
+@pytest.mark.parametrize("layout", ["gqa", "mla"])
+def test_block_walk_all_idle_batch_is_finite(layout, rng, monkeypatch):
+    # every row a null-page row: the walk makes no trip, and the
+    # zero-sum guard keeps 0/0 out of the result
+    case, _ = _walk_case(rng, monkeypatch, "prefix", 1, layout)
+    null = case["k_pool"].shape[0] - 1
+    case["pages"] = jnp.full_like(case["pages"], null)
+    case["index"] = jnp.full_like(case["index"], IDLE)
+    got = np.asarray(jax.jit(paged_attention_xla)(**case))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, 0.0)
+
+
+def test_engine_index_mirror_tracks_device(rng):
+    """The serve engine's host mirror of the cache's per-row write
+    positions (which feeds the walk counters) matches the device's after
+    every step: admissions, finished slots whose rows keep advancing, and
+    chunked prefills alike; the walk never counts more than a full one."""
+    engine = ServeEngine(F32, n_slots=3, max_len=32, seed=0, page_size=4,
+                         prefill_chunk=6)
+    for n, g in ((5, 3), (13, 6), (3, 9), (9, 2)):
+        engine.submit(Request(rng.integers(0, CFG.vocab_size, n).tolist(),
+                              max_new_tokens=g))
+    while engine.scheduler.has_work:
+        engine.step()
+        np.testing.assert_array_equal(
+            engine._dev_index, np.asarray(engine.cache["index"])
+        )
+    walked = engine.registry.counter("serve_paged_walk_blocks_total")
+    full = engine.registry.counter("serve_paged_walk_blocks_full_total")
+    assert 0 < walked.value <= full.value
+
 
 
 def test_scatter_chunk_matches_token_scatter(rng):
@@ -281,19 +377,19 @@ def test_pallas_target_legality_is_tpu_only():
 
     cons = kernels.BLOCK_LEGALITY[("paged_attention", "pallas")]
     assert cons.requires_platform == ("tpu",)
-    # the gather path runs anywhere — it's the measured CPU baseline
+    # the XLA walk runs anywhere — it is the measured CPU baseline
     assert not kernels.BLOCK_LEGALITY[
         ("paged_attention", "xla")].requires_platform
 
 
-# -- static resources: fused walk beats the gathered view ----------------------
+# -- static resources: the fused kernel holds no gathered block ----------------
 
 
 def test_fused_decode_peak_live_bytes_below_gather():
     """At serving-scale shapes the fused program's peak live bytes sit
-    strictly below the gather path's — the gathered per-slot K/V view is
-    the dominant decode intermediate, and the fused kernel never
-    materialises it."""
+    strictly below the XLA walk's: the walk gathers a block of pages per
+    slot (at these widths one block spans every page), the fused kernel
+    reads one page at a time in VMEM."""
     from repro.analysis.resources import estimate_memory
     from repro.core import blocks
     from repro.offload.zoo import _cell_target
@@ -315,7 +411,7 @@ def test_fused_decode_peak_live_bytes_below_gather():
 def test_zoo_decode_plan_searches_paged_block(tmp_path):
     """The zoo decode cell exposes ``paged_attention`` as a search axis:
     on CPU the legality pass prunes every pallas candidate statically
-    (the fused kernel is TPU-only), the measured winner binds the gather
+    (the fused kernel is TPU-only), the measured winner binds the XLA
     implementation, and the committed plan records the block."""
     from repro.offload.zoo import plan_zoo
 

@@ -678,7 +678,7 @@ def test_op_scopes_on_the_paged_decode_program(paged_traced_engine):
         m = re.search(r"%([\w.\-]+) = .* while\(.*body=%([\w.\-]+)", line)
         if m and scopes[m.group(1)] == "paged_attention":
             walks.append(m.group(2))
-    assert len(walks) >= 2  # the K and the V page walks
+    assert len(walks) >= 1  # one walk reads K and V together
     for body in walks:
         # constants and tuple plumbing carry whichever op XLA merged or
         # widened them from; they take no device time

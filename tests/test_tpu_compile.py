@@ -211,7 +211,7 @@ def test_paged_decode_page_walk_in_its_block_scope(one_chip):
             if m:
                 bodies[m.group(1)] = m.group(2)
     walks = [w for w in bodies if scopes[w] == "paged_attention"]
-    assert len(walks) >= 2  # the K and the V walk
+    assert len(walks) >= 1  # one walk reads K and V together
     for w in walks:
         for line in lines[bodies[w]]:
             if "op_name=" in line and not FREE.search(line):
