@@ -8,13 +8,43 @@ from typing import Literal
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int  # the router's outputs: every expert of the layer
     top_k: int
     d_expert: int  # expert FFN hidden size
     n_shared: int = 0  # always-on shared experts (deepseek)
     dense_residual: bool = False  # dense FFN in parallel with MoE (arctic)
     capacity_factor: float = 1.25
     aux_loss_coef: float = 0.01
+    # renormalise the top-k gate weights to sum to 1 (False: the softmax
+    # weights as they are, DeepSeek-V2's norm_topk_prob=false)
+    norm_topk_prob: bool = True
+    # the experts this device holds, [experts_first, experts_first +
+    # n_held): the router still scores all n_experts, and a token's pairs
+    # with experts held elsewhere add nothing here (expert parallelism's
+    # share of one device); None holds every expert
+    experts_first: int = 0
+    n_held: int | None = None
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this device holds."""
+        return self.n_experts if self.n_held is None else self.n_held
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071), DeepSeek-V2's form: rope
+    channel pairs below the correction range keep their frequency, those
+    above it are divided by ``factor``, a linear ramp between; the softmax
+    scale gains ``mscale(mscale_all_dim)**2`` and cos/sin are multiplied
+    by ``mscale(mscale) / mscale(mscale_all_dim)``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,6 +91,7 @@ class ArchConfig:
     # 's' shared attention block (parameters shared across all 's' sites)
     block_pattern: str | None = None
     rope_theta: float = 10000.0
+    rope_scaling: YarnScaling | None = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     frontend: Literal["patch_embed", "audio_tokens"] | None = None
@@ -140,7 +171,7 @@ class ArchConfig:
         if self.moe is not None and ch == "a":
             m = self.moe
             total += d * m.n_experts  # router
-            total += m.n_experts * self._mlp_params(m.d_expert) // 1
+            total += m.held * self._mlp_params(m.d_expert)
             total += m.n_shared * self._mlp_params(m.d_expert)
             if m.dense_residual:
                 total += self._mlp_params(self.d_ff)

@@ -174,7 +174,7 @@ def make_prefill_step(cfg: ArchConfig, shape: ShapeConfig):
         # the full prompt but project just the final hidden state — the full
         # (B, S, V) logits tensor (tens of GB at 32k x 128k-vocab) is never
         # materialised.
-        x, _, new_cache = lm.backbone(params, batch, cfg, "prefill", cache)
+        x, _, new_cache, _ = lm.backbone(params, batch, cfg, "prefill", cache)
         logits_last = lm.head(params, x[:, -1:, :], cfg)
         new_cache["index"] = jnp.full(
             (shape.global_batch,), shape.seq_len, jnp.int32
@@ -186,7 +186,7 @@ def make_prefill_step(cfg: ArchConfig, shape: ShapeConfig):
 
 def make_decode_step(cfg: ArchConfig):
     def decode_step(params, cache, batch):
-        logits, new_cache = lm.decode_step(params, batch["tokens"], cfg, cache)
+        logits, new_cache, _ = lm.decode_step(params, batch["tokens"], cfg, cache)
         return logits[:, 0, :], new_cache
 
     return decode_step

@@ -30,7 +30,12 @@ from repro.kernels.paged_attention import (  # noqa: F401
     scatter_chunk_pages,
     scatter_token_pages,
 )
-from repro.models.layers import rmsnorm, rope, tp_out_einsum
+from repro.models.layers import (
+    rmsnorm,
+    rope,
+    tp_out_einsum,
+    yarn_mscale,
+)
 from repro.models.params import ParamMeta
 from repro.sharding.utils import constrain
 
@@ -193,8 +198,8 @@ def gqa_forward(
     q = jnp.einsum("bsd,dq->bsq", xc, p["wq"].astype(cd)).reshape(b, s, h, dh)
     k = jnp.einsum("bsd,dq->bsq", xc, p["wk"].astype(cd)).reshape(b, s, kh, dh)
     v = jnp.einsum("bsd,dq->bsq", xc, p["wv"].astype(cd)).reshape(b, s, kh, dh)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+    k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     q = constrain(q, "act_batch", None, "heads_act", None)
     k = constrain(k, "act_batch", None, "kv_heads_act", None)
 
@@ -276,12 +281,18 @@ def mla_forward(
     q = jnp.einsum("bsd,dq->bsq", xc, p["wq"].astype(cd))
     q = q.reshape(b, s, h, dn + dr)
     qn, qr = q[..., :dn], q[..., dn:]
-    qr = rope(qr, positions, cfg.rope_theta)
+    yarn = cfg.rope_scaling
+    qr = rope(qr, positions, cfg.rope_theta, yarn)
 
     c = jnp.einsum("bsd,dr->bsr", xc, p["w_dkv"].astype(cd))
     c = rmsnorm(p["kv_norm"], c, cfg.norm_eps).astype(cd)
     kr = jnp.einsum("bsd,dr->bsr", xc, p["w_kr"].astype(cd))
-    kr = rope(kr[:, :, None, :], positions, cfg.rope_theta)  # (B,S,1,dr)
+    kr = rope(kr[:, :, None, :], positions, cfg.rope_theta, yarn)  # (B,S,1,dr)
+    # YaRN scales the softmax by mscale(mscale_all_dim)^2 on top of
+    # 1/sqrt(dn + dr), at every position
+    mscale2 = 1.0
+    if yarn is not None:
+        mscale2 = yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
 
     if mode in ("decode", "extend"):
         assert cache is not None and index is not None
@@ -289,7 +300,7 @@ def mla_forward(
         # GQA with one KV head whose keys/values are the latent cache
         w_uk = p["w_uk"].astype(cd).reshape(m.kv_lora_rank, h, dn)
         q_abs = jnp.einsum("bshn,rhn->bshr", qn, w_uk)  # (B,S,H,r)
-        scale = 1.0 / ((dn + dr) ** 0.5)
+        scale = mscale2 / ((dn + dr) ** 0.5)
         if pages is not None:
             if s == 1:
                 c_cache = scatter_token_pages(
@@ -355,6 +366,10 @@ def mla_forward(
         v = v.reshape(b, s, h, dv)
         k = jnp.concatenate([kn, jnp.broadcast_to(kr, (b, s, h, dr))], axis=-1)
         qf = jnp.concatenate([qn, qr], axis=-1)
+        if mscale2 != 1.0:
+            # the attention block scales by 1/sqrt(dn + dr); YaRN's factor
+            # goes on q first
+            qf = (qf.astype(jnp.float32) * mscale2).astype(cd)
         # pin head sharding: the broadcast of the shared rope key otherwise
         # propagates "replicated heads" into the whole attention region and
         # GSPMD all-gathers every (B,H,S,D) block — TBs/step at 128 heads

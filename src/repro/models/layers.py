@@ -6,10 +6,12 @@ shelf kernel exists, so the offload engine can re-bind implementations.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, YarnScaling
 from repro.core import blocks
 from repro.models.params import ParamMeta
 from repro.sharding.utils import constrain
@@ -68,19 +70,62 @@ def rmsnorm(w: jax.Array, x: jax.Array, eps: float) -> jax.Array:
     return blocks.call("rmsnorm", x, w, eps=eps)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding, llama-style rotate-half.
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-magnitude factor ``0.1 * mscale * ln(factor) + 1``
+    (1 at ``factor`` <= 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_range(d: int, theta: float, scaling: YarnScaling) -> tuple[int, int]:
+    """(low, high) rope channel pairs of YaRN's ramp, clamped to
+    ``[0, d - 1]``: pair ``i`` turns ``r`` times over the original context
+    at ``i = d * ln(L / (2 pi r)) / (2 ln theta)``; ``low`` is where it
+    turns ``beta_fast`` times (rounded down), ``high`` ``beta_slow``
+    times (rounded up)."""
+    length = scaling.original_max_position_embeddings
+
+    def at(turns):
+        return d * math.log(length / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    return (max(math.floor(at(scaling.beta_fast)), 0),
+            min(math.ceil(at(scaling.beta_slow)), d - 1))
+
+
+def rope_inv_freq(d: int, theta: float,
+                  scaling: YarnScaling | None = None) -> jax.Array:
+    """(d/2,) float32 inverse frequencies ``theta^(-2i/d)``; under YaRN,
+    ``f_inter (1 - m) + f_extra m`` with ``f_extra = theta^(-2i/d)``,
+    ``f_inter = f_extra / factor`` and ``m = 1 - clip((i - low) / (high -
+    low), 0, 1)``."""
+    half = d // 2
+    extra = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return extra
+    low, high = yarn_range(d, theta, scaling)
+    ramp = (jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3)
+    m = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return extra / scaling.factor * (1.0 - m) + extra * m
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         scaling: YarnScaling | None = None) -> jax.Array:
+    """Rotary embedding, llama-style rotate-half; YaRN frequencies and
+    cos/sin magnitude under ``scaling``.
 
     x: (B, S, H, d); positions: (B, S) int32.
     """
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
-    )  # (half,)
+    freqs = rope_inv_freq(d, theta, scaling)  # (half,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B,S,half)
     cos = jnp.cos(ang)[:, :, None, :]  # (B,S,1,half)
     sin = jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        mag = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim
+        )
+        if mag != 1.0:
+            cos, sin = cos * mag, sin * mag
     x1, x2 = x[..., :half], x[..., half:]
     xf1 = x1.astype(jnp.float32)
     xf2 = x2.astype(jnp.float32)

@@ -56,6 +56,11 @@ from repro.models.ssm import ssm_forward, ssm_metas, ssm_state_metas
 from repro.sharding.utils import constrain
 
 
+def _no_load() -> jax.Array:
+    """The MoE load of a layer without routed experts."""
+    return jnp.zeros((2,), jnp.int32)
+
+
 # -- pattern grouping -----------------------------------------------------------
 
 
@@ -219,14 +224,14 @@ def _apply_attn_block(
         rmsnorm(lp["ln2"], x, cfg.norm_eps).astype(cd)
     )
     if kind == "a" and cfg.moe is not None:
-        ff, aux = moe_forward(lp["moe"], ff_in, cfg, cd)
+        ff, aux, load = moe_forward(lp["moe"], ff_in, cfg, cd, mode)
     else:
         with jax.named_scope("mlp"):
             ff = mlp_forward(lp["mlp"], ff_in, cd)
-        aux = jnp.asarray(0.0, jnp.float32)
+        aux, load = jnp.asarray(0.0, jnp.float32), _no_load()
     x = x + ff.astype(x.dtype)
     x = constrain(x, "act_batch", "act_seq", None)
-    return x, aux, new_cache
+    return x, aux, load, new_cache
 
 
 def _apply_mamba_block(lp, x, cfg, cache, mode):
@@ -243,29 +248,30 @@ def _apply_mamba_block(lp, x, cfg, cache, mode):
     out, new_state = ssm_forward(lp["mixer"], h_in, cfg, cache, mode)
     x = x + out.astype(x.dtype)
     x = constrain(x, "act_batch", "act_seq", None)
-    return x, jnp.asarray(0.0, jnp.float32), new_state
+    return x, jnp.asarray(0.0, jnp.float32), _no_load(), new_state
 
 
 def _apply_group(
     gparams, g: Group, x, cfg, positions, gcache, index, mode, shared_params,
     pages=None,
 ):
-    """Scan a homogeneous group of layers; returns (x, aux_sum, new_gcache)."""
+    """Scan a homogeneous group of layers; returns (x, aux_sum, load_sum,
+    new_gcache)."""
     use_cache = gcache is not None
     shared = g.kind == "s"
 
-    def apply_one(x, aux, lp, lcache):
+    def apply_one(x, aux, load, lp, lcache):
         p = shared_params if shared else lp
         if g.kind == "m":
-            x, a, nc = _apply_mamba_block(p, x, cfg, lcache, mode)
+            x, a, ld, nc = _apply_mamba_block(p, x, cfg, lcache, mode)
         else:
-            x, a, nc = _apply_attn_block(
+            x, a, ld, nc = _apply_attn_block(
                 p, x, cfg, positions, lcache, index, mode, g.kind, pages
             )
-        return x, aux + a, nc
+        return x, aux + a, load + ld, nc
 
-    def layer(x_aux, xs):
-        x, aux = x_aux
+    def layer(carry, xs):
+        x, aux, load = carry
         # barrier: prevents XLA from hoisting dtype converts of the stacked
         # layer-input residuals out of the scan (an f32 copy of every
         # saved carry doubles remat memory otherwise)
@@ -276,8 +282,8 @@ def _apply_group(
             lp, lcache = xs
         else:
             lp, lcache = xs, None
-        x, aux, nc = apply_one(x, aux, lp, lcache)
-        return (x, aux), nc
+        x, aux, load, nc = apply_one(x, aux, load, lp, lcache)
+        return (x, aux, load), nc
 
     body = layer
     if cfg.remat == "full" and mode == "train":
@@ -290,10 +296,10 @@ def _apply_group(
     if shared and not use_cache:
         # cache-less shared blocks: unrolled application (count is small
         # and there are no per-site parameters to stack)
-        aux_t = zero
+        aux_t, load_t = zero, _no_load()
         for _ in range(g.count):
-            x, aux_t, _ = apply_one(x, aux_t, None, None)
-        return x, aux_t, None
+            x, aux_t, load_t, _ = apply_one(x, aux_t, load_t, None, None)
+        return x, aux_t, load_t, None
 
     if shared:
         xs = gcache  # scan each site's cache under the shared params
@@ -301,8 +307,8 @@ def _apply_group(
         xs = (gparams, gcache)
     else:
         xs = gparams
-    (x, aux), new_cache = jax.lax.scan(body, (x, zero), xs)
-    return x, aux, (new_cache if use_cache else None)
+    (x, aux, load), new_cache = jax.lax.scan(body, (x, zero, _no_load()), xs)
+    return x, aux, load, (new_cache if use_cache else None)
 
 
 # -- forward / loss / serve ---------------------------------------------------------
@@ -322,7 +328,10 @@ def backbone(
     mode: str = "train",
     cache: Any = None,
 ):
-    """All blocks, no head.  Returns (hidden (B,S,D), aux_loss, new_cache)."""
+    """All blocks, no head.  Returns (hidden (B,S,D), aux_loss, new_cache,
+    moe_load): ``moe_load`` (2,) int32 sums, over the MoE layers, the
+    serving dispatch's (token, held expert) pairs and each layer's busiest
+    held expert's pairs (zeros without MoE layers or in training)."""
     x = _input_embeds(params, batch, cfg)
     b, s = x.shape[0], x.shape[1]
     x = constrain(x, "act_batch", "act_seq", None)
@@ -346,18 +355,20 @@ def backbone(
         )
 
     aux_total = jnp.asarray(0.0, jnp.float32)
+    load_total = _no_load()
     new_cache: dict = {} if cache is not None else None
     shared = params.get("shared_block")
     for g in groups_of(cfg):
         gparams = None if g.kind == "s" else params["blocks"][g.key]
         gcache = cache[g.key] if cache is not None else None
-        x, aux, nc = _apply_group(
+        x, aux, load, nc = _apply_group(
             gparams, g, x, cfg, positions, gcache, index, mode, shared, pages
         )
         aux_total = aux_total + aux
+        load_total = load_total + load
         if cache is not None:
             new_cache[g.key] = nc
-    return x, aux_total, new_cache
+    return x, aux_total, new_cache, load_total
 
 
 @jax.named_scope("head")
@@ -374,7 +385,12 @@ def forward(
     cache: Any = None,
 ):
     """Returns (logits, aux_loss, new_cache)."""
-    x, aux_total, new_cache = backbone(params, batch, cfg, mode, cache)
+    return _forward(params, batch, cfg, mode, cache)[:3]
+
+
+def _forward(params, batch, cfg, mode, cache):
+    """``forward`` and the MoE load (see ``backbone``)."""
+    x, aux_total, new_cache, load = backbone(params, batch, cfg, mode, cache)
     s = x.shape[1]
     logits = head(params, x, cfg)
     if cache is not None:
@@ -385,11 +401,11 @@ def forward(
                 (batch["tokens" if "tokens" in batch else "embeds"].shape[0],),
                 s, jnp.int32,
             )
-    return logits, aux_total, new_cache
+    return logits, aux_total, new_cache, load
 
 
 def loss_fn(params: Any, batch: dict, cfg: ArchConfig):
-    x, aux, _ = backbone(params, batch, cfg, mode="train")
+    x, aux, _, _ = backbone(params, batch, cfg, mode="train")
 
     def head_loss(p, xx, labels):
         logits = head(p, xx, cfg)
@@ -412,10 +428,11 @@ def prefill(params: Any, batch: dict, cfg: ArchConfig, cache: Any):
 
 
 def decode_step(params: Any, tokens: jax.Array, cfg: ArchConfig, cache: Any):
-    """tokens (B, 1) -> (logits (B,1,V), new_cache).  cache["index"] (B,)
-    is each row's write position for this token — rows may sit at
-    different positions (continuous batching)."""
-    logits, _, new_cache = forward(
-        params, {"tokens": tokens}, cfg, mode="decode", cache=cache
+    """tokens (B, 1) -> (logits (B,1,V), new_cache, moe_load).
+    cache["index"] (B,) is each row's write position for this token — rows
+    may sit at different positions (continuous batching).  ``moe_load`` is
+    ``backbone``'s (zeros without MoE layers)."""
+    logits, _, new_cache, load = _forward(
+        params, {"tokens": tokens}, cfg, "decode", cache
     )
-    return logits, new_cache
+    return logits, new_cache, load

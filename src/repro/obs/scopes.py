@@ -4,7 +4,9 @@ Every function block the models call (``paged_attention``, ``attention``,
 ``rmsnorm``, ``ssd_scan``) runs under ``jax.named_scope(<block>)``
 (:meth:`repro.core.blocks.FunctionBlockRegistry.call`), whichever target
 is bound; the paged KV writes run under ``kv_write``, the LM head under
-``head``, the MLP under ``mlp`` and sampling under ``sample``.  XLA keeps
+``head``, the MLP under ``mlp``, the expert layer under ``moe`` (its
+router under ``router``, its expert products under ``experts``) and
+sampling under ``sample``.  XLA keeps
 the scope path in each instruction's ``op_name`` metadata, through fusion
 and inside while bodies.  A profiler trace captured without HLO protos
 names only the instruction (``fusion.3``, ``while.42``), so

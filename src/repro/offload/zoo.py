@@ -228,7 +228,7 @@ def _cell_target(
         args = (params, batch_tree, lm.init_cache(cfg, batch, seq))
     elif kind == "decode":
         def builder():
-            return jax.jit(lambda p, t, c: lm.decode_step(p, t, cfg, c))
+            return jax.jit(lambda p, t, c: lm.decode_step(p, t, cfg, c)[:2])
 
         # attention-family decode traces through the block-paged KV pool
         # (the serving layout), so the cell's binding space includes the

@@ -322,6 +322,20 @@ class ServeEngine:
         self._step_hist = self.registry.histogram(
             "serve_step_seconds", "fused decode step latency"
         )
+        self._moe_pairs_c = self._moe_busiest_c = None
+        if cfg.moe is not None:
+            # summed over decode calls and MoE layers; their ratio times
+            # the held experts is a step's busiest/mean expert load
+            self._moe_pairs_c = self.registry.counter(
+                "serve_moe_pairs_total",
+                "(token, held expert) pairs the decode steps computed, "
+                "over every MoE layer",
+            )
+            self._moe_busiest_c = self.registry.counter(
+                "serve_moe_busiest_total",
+                "the busiest held expert's pairs of each decode step, "
+                "summed over the MoE layers",
+            )
         self.monitor = monitor or StepMonitor()
         if self.monitor.histogram is None:
             self.monitor.histogram = self._step_hist
@@ -584,7 +598,7 @@ class ServeEngine:
             from repro.models import params as pm
 
             cache = pm.init_params(cache_metas, 0)
-            x, _, new_cache = lm.backbone(
+            x, _, new_cache, _ = lm.backbone(
                 params, {"tokens": tokens}, cfg, "prefill", cache
             )
             x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
@@ -608,14 +622,19 @@ class ServeEngine:
         def decode_fn(params, tokens, cache, pages, seeds, steps, temps, topks):
             """One fused (logits -> token) step for the whole slot batch.
             ``pages`` is the page-table operand (paged mode; unused
-            otherwise) — recomposing the batch never retraces."""
+            otherwise) — recomposing the batch never retraces.  With MoE
+            layers the step's expert load (pairs computed, busiest held
+            expert's pairs, over the layers) follows the (B,) token ids:
+            one transfer carries both."""
             if paged:
                 cache = dict(cache, pages=pages)
-            logits, new_cache = lm.decode_step(params, tokens, cfg, cache)
+            logits, new_cache, load = lm.decode_step(params, tokens, cfg, cache)
             new_cache.pop("pages", None)
             tok = sample_tokens(
                 logits[:, 0, : cfg.vocab_size], seeds, steps, temps, topks
             )
+            if cfg.moe is not None:
+                tok = jnp.concatenate([tok, load])
             return tok, new_cache
 
         return decode_fn
@@ -626,7 +645,7 @@ class ServeEngine:
         def extend_fn(params, tokens, cache):
             """One non-final prefill chunk: extend the per-request cache
             by ``tokens`` (1, C), no sampling, no head matmul."""
-            _, _, new_cache = lm.backbone(
+            _, _, new_cache, _ = lm.backbone(
                 params, {"tokens": tokens}, cfg, "extend", cache
             )
             new_cache["index"] = cache["index"] + tokens.shape[1]
@@ -642,7 +661,7 @@ class ServeEngine:
         ):
             """The final prefill chunk: extend, project only the last real
             position and sample the request's first token."""
-            x, _, new_cache = lm.backbone(
+            x, _, new_cache, _ = lm.backbone(
                 params, {"tokens": tokens}, cfg, "extend", cache
             )
             x_last = jax.lax.dynamic_slice_in_dim(x, last_off, 1, axis=1)
@@ -1308,9 +1327,13 @@ class ServeEngine:
                     jnp.asarray(self._topks),
                 )
             with span("serve.decode_wait"):
-                # the only device->host transfer: (B,) token ids
+                # the only device->host transfer: (B,) token ids (and,
+                # with MoE layers, the step's expert load behind them)
                 toks = np.asarray(tok)
             at = time.perf_counter()
+        if self._moe_pairs_c is not None:
+            self._moe_pairs_c.inc(int(toks[self.n_slots]))
+            self._moe_busiest_c.inc(int(toks[self.n_slots + 1]))
         self._dev_index += 1
         self.monitor.stop(self._steps)
         self.telemetry["decode"].add(tele, len(active))

@@ -72,11 +72,15 @@ def test_prefill_decode_consistency(arch, rng):
     params = lm.init_params(cfg, seed=0)
     s, maxlen = 16, 32
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, s + 1)), jnp.int32)
-    logits_full, _, _ = lm.forward(params, {"tokens": toks}, cfg, mode="train")
+    # training routes experts with a per-row capacity and drops overflow
+    # tokens, so a drop there depends on S; serving is dropless, and its
+    # full-sequence forward (prefill mode, no cache) is the reference
+    full_mode = "prefill" if cfg.moe else "train"
+    logits_full, _, _ = lm.forward(params, {"tokens": toks}, cfg, mode=full_mode)
     cache = lm.init_cache(cfg, B, maxlen)
     logits_pre, cache = lm.prefill(params, {"tokens": toks[:, :s]}, cfg, cache)
-    logits_dec, cache = lm.decode_step(params, toks[:, s : s + 1], cfg, cache)
-    tol = 5e-2 if cfg.moe else 1e-3  # MoE capacity drops differ with S
+    logits_dec, cache, _ = lm.decode_step(params, toks[:, s : s + 1], cfg, cache)
+    tol = 1e-3  # float32 throughout: cache vs full-sequence summation order
     np.testing.assert_allclose(
         np.asarray(logits_dec[:, 0]), np.asarray(logits_full[:, s]),
         rtol=tol, atol=tol,

@@ -218,3 +218,74 @@ def test_paged_decode_page_walk_in_its_block_scope(one_chip):
                 name = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)", line).group(1)
                 assert scopes[name] == "paged_attention", line
     assert {"kv_write", "head", "sample", "mlp"} <= set(scopes.values())
+
+
+def _bench_module(name, path):
+    import importlib.util
+    import pathlib
+
+    spec = importlib.util.spec_from_file_location(
+        name, pathlib.Path(__file__).resolve().parents[1] / path
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_deepseek_v2_lite_cell_programs_fit_the_chip(one_chip):
+    """The deepseek-v2-lite cell at its published widths, its held expert
+    share and its engine size (``bench/configs/deepseek-v2-lite.json``):
+    the decode step over the whole page pool fits the sizing rule's share
+    of the chip's allocator limit and the longest prefill bucket beside
+    that pool fits the limit, compiled for the described chip; the expert
+    layers run the chip's grouped (ragged) kernel."""
+    import json
+    import pathlib
+
+    from repro.models import lm
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    config = json.loads(
+        (root / "bench/configs/deepseek-v2-lite.json").read_text())
+    adapter = _bench_module("adapter_mla_moe", "bench/adapters/mla_moe.py")
+    ref = _bench_module("reference_mla_moe", "bench/reference/mla_moe.py")
+    # the chip's allocator limit and the compiler's own reservation
+    limit = _bench_module("sizing", "bench/sizing.py")
+    eng = config["engine"]
+    slots, pages, max_len = eng["n_slots"], eng["n_pages"], eng["max_len"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    cfg = adapter.arch_config(config)
+    params = adapter.program_params(
+        {k: sds(s, config["dtypes"]["param"])
+         for k, (s, _) in ref.layout(config).items()}, cfg)
+    cache = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: lm.init_cache(
+            cfg, slots, max_len, page_size=eng["page_size"], n_pages=pages)),
+    )
+    engine = adapter.engine(dict(config, engine=dict(eng, n_pages=1)), cfg,
+                            params, 0)
+    i32 = functools.partial(sds, dtype="int32")
+    decode = engine.programs.records["decode"].fn.lower(
+        params, i32((slots, 1)), cache, i32((slots, max_len // eng["page_size"])),
+        i32((slots,)), i32((slots,)), sds((slots,), "float32"), i32((slots,)),
+    ).compile()
+    m = decode.memory_analysis()
+    # the sizing rule keeps its margin of the limit free
+    assert (limit.RESERVED + m.argument_size_in_bytes + m.temp_size_in_bytes
+            <= (1 - limit.MARGIN) * limit.BYTES_LIMIT)
+    assert "ragged" in decode.as_text()
+
+    longest = max_len  # the mix's longest context rounds up to max_len
+    prefill = engine.programs.records["prefill"].fn.lower(
+        params, i32((1, longest)), i32(()), i32(()), i32(()),
+        sds((), "float32"), i32(()),
+    ).compile()
+    p = prefill.memory_analysis()
+    pool = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert (limit.RESERVED + p.argument_size_in_bytes + p.temp_size_in_bytes
+            + p.output_size_in_bytes + pool) <= limit.BYTES_LIMIT
