@@ -55,14 +55,10 @@ def make_operands(rng, *, batch, heads, kv_heads, head_dim, seq, page_size):
     """Ragged decode operands: per-slot lengths spread across [1, seq]."""
     max_pages = -(-seq // page_size)
     n_pages = batch * max_pages
-    k_pool = jnp.asarray(
-        rng.standard_normal((n_pages + 1, kv_heads, page_size, head_dim)),
-        jnp.float32,
-    )
-    v_pool = jnp.asarray(
-        rng.standard_normal((n_pages + 1, kv_heads, page_size, head_dim)),
-        jnp.float32,
-    )
+    # one layer of the stacked token-major pools
+    pool_shape = (1, n_pages + 1, page_size, kv_heads * head_dim)
+    k_pool = jnp.asarray(rng.standard_normal(pool_shape), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal(pool_shape), jnp.float32)
     q = jnp.asarray(
         rng.standard_normal((batch, heads, 1, head_dim)), jnp.float32
     )
@@ -82,7 +78,7 @@ def bench_cell(args, page_size, backend, interpret):
 
     def step(q, k_pool, v_pool, pages, index):
         return ops.paged_attention(
-            q, k_pool, v_pool, pages, index,
+            q, k_pool, v_pool, pages, index, 0,
             backend=backend, interpret=interpret or None,
         )
 

@@ -163,8 +163,9 @@ def _resource_metadata() -> dict[tuple[str, str], ResourceHint]:
               "plus their float32 copies; independent of max_pages",
     )
     # fused kernel: NO gather multiplier — the working set is the q rows
-    # plus one (page_size, head_dim) block per K/V operand plus the
-    # online-softmax scratch, all VMEM-resident per grid step
+    # plus one whole token-major page (page_size, KH * head_dim) per K/V
+    # operand plus the online-softmax scratch, all VMEM-resident per grid
+    # step
     out[("paged_attention", "pallas")] = ResourceHint(
         vmem_tile_bytes=4 * tile * tile * f32,
         notes="q rows + one K/V page block per operand + acc/stats "

@@ -161,11 +161,12 @@ def flash_attention(
 
 
 def paged_attention(
-    q, k_pool, v_pool, pages, index, *, q_rope=None, kr_pool=None,
+    q, k_pool, v_pool, pages, index, layer, *, q_rope=None, kr_pool=None,
     scale: float | None = None, backend: str | None = None,
     interpret: bool | None = None,
 ):
-    """Paged decode/extend attention through the page table.
+    """Paged decode/extend attention through the page table, over layer
+    ``layer`` of the stacked token-major pools.
 
     pallas: the fused page-walk kernel; xla: a rolled walk over the live
     blocks of pages with an online softmax.  When the pallas target is
@@ -178,12 +179,12 @@ def paged_attention(
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
         return _paged.paged_attention_pallas(
-            q, k_pool, v_pool, pages, index, q_rope=q_rope, kr_pool=kr_pool,
-            scale=scale, interpret=interpret,
+            q, k_pool, v_pool, pages, index, layer, q_rope=q_rope,
+            kr_pool=kr_pool, scale=scale, interpret=interpret,
         )
     return _paged.paged_attention_xla(
-        q, k_pool, v_pool, pages, index, q_rope=q_rope, kr_pool=kr_pool,
-        scale=scale,
+        q, k_pool, v_pool, pages, index, layer, q_rope=q_rope,
+        kr_pool=kr_pool, scale=scale,
     )
 
 

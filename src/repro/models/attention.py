@@ -12,6 +12,8 @@ Three execution paths per mixer:
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -27,8 +29,7 @@ from repro.kernels.attention_xla import attention_chunked  # noqa: F401
 # serve engine's page insert; re-exported from the kernel layer
 from repro.kernels.paged_attention import (  # noqa: F401
     insert_pages,
-    scatter_chunk_pages,
-    scatter_token_pages,
+    scatter_pages,
 )
 from repro.models.layers import (
     rmsnorm,
@@ -105,26 +106,29 @@ def cache_metas(cfg: ArchConfig, batch: int, max_len: int) -> dict:
 def cache_metas_paged(
     cfg: ArchConfig, n_pages_total: int, page_size: int
 ) -> dict:
-    """Block-paged pool layout: the contiguous layout with the batch axis
-    reinterpreted as a *shared page pool* (``n_pages_total`` includes the
-    null page) and the sequence axis shrunk to one page.  Slot identity
-    moves out of the storage entirely — it lives in the page table the
-    decode program gathers through — so pool axes carry no batch/sequence
-    sharding names (multi-device serving shards slots, not pages)."""
+    """Block-paged pool layout: a *shared page pool* of ``n_pages_total``
+    pages (the null page included) of ``page_size`` token rows, each row
+    token-major: the contiguous leaf's per-token axes flattened side by
+    side (GQA ``KH * D``, MLA ``r`` and ``dr``), so a decode step's new
+    token is one row of every page it writes.  Slot identity moves out of
+    the storage entirely — it lives in the page table the decode program
+    gathers through — so the page and row axes carry no sharding names
+    (multi-device serving shards slots, not pages)."""
     out = {}
-    for key, m in cache_metas(cfg, n_pages_total, page_size).items():
-        axes = tuple(
-            None if a in ("act_batch", "cache_seq") else a for a in m.axes
+    for key, m in cache_metas(cfg, 1, 1).items():
+        head = [a for a in m.axes if a not in ("act_batch", "cache_seq")]
+        out[key] = ParamMeta(
+            (n_pages_total, page_size, math.prod(m.shape)),
+            (None, None, next((a for a in head if a), None)),
+            m.dtype, m.init, m.scale,
         )
-        out[key] = ParamMeta(m.shape, axes, m.dtype, m.init, m.scale)
     return out
 
 
 def cache_seq_axes(cfg: ArchConfig) -> dict:
     """Leaf name -> sequence-axis position in the per-layer contiguous
-    cache leaf (batch leading).  The same position holds the within-page
-    axis in the paged pool layout — the engine's page-insert uses this to
-    split a prefilled slot cache into whole pages."""
+    cache leaf (batch leading) — the engine's page-insert uses this to
+    turn a prefilled slot cache into whole token-major pages."""
     return {
         key: m.axes.index("cache_seq")
         for key, m in cache_metas(cfg, 1, 1).items()
@@ -190,7 +194,12 @@ def gqa_forward(
     index: jax.Array | None = None,
     mode: str = "train",
     pages: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ):
+    """``pages`` given (paged decode/extend): ``cache`` is the attention
+    group's stacked pools and ``layer`` this layer's index in them; the
+    new rows are written into the pools in place and the new pools
+    returned."""
     b, s, d = x.shape
     cd = jnp.dtype(cfg.compute_dtype)
     xc = x.astype(cd)
@@ -210,25 +219,19 @@ def gqa_forward(
     if mode in ("decode", "extend"):
         assert cache is not None and index is not None
         if pages is not None:
-            if s == 1:
-                k_cache = scatter_token_pages(
-                    cache["k"], kt[:, :, 0, :], pages, index, seq_axis=2
-                )
-                v_cache = scatter_token_pages(
-                    cache["v"], vt[:, :, 0, :], pages, index, seq_axis=2
-                )
-            else:  # extend: S-token chunk, causal within the chunk
-                k_cache = scatter_chunk_pages(
-                    cache["k"], kt, pages, index, seq_axis=2
-                )
-                v_cache = scatter_chunk_pages(
-                    cache["v"], vt, pages, index, seq_axis=2
-                )
+            # one token-major row per new token (S > 1: an extend chunk),
+            # its KV heads side by side
+            k_cache = scatter_pages(
+                cache["k"], k.reshape(b, s, kh * dh), pages, index, layer
+            )
+            v_cache = scatter_pages(
+                cache["v"], v.reshape(b, s, kh * dh), pages, index, layer
+            )
             # the attention read is a planner-searchable function block:
             # xla = a walk over the live page blocks with an online
             # softmax, pallas = the fused page-walk kernel
             o = blocks.call(
-                "paged_attention", qt, k_cache, v_cache, pages, index
+                "paged_attention", qt, k_cache, v_cache, pages, index, layer
             )
             new_cache = {"k": k_cache, "v": v_cache}
         else:
@@ -270,7 +273,10 @@ def mla_forward(
     index: jax.Array | None = None,
     mode: str = "train",
     pages: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ):
+    """Paged decode/extend as in ``gqa_forward``: the latent and rope
+    pools are the group's stacked pools, written at ``layer``."""
     m = cfg.mla
     b, s, d = x.shape
     cd = jnp.dtype(cfg.compute_dtype)
@@ -302,28 +308,18 @@ def mla_forward(
         q_abs = jnp.einsum("bshn,rhn->bshr", qn, w_uk)  # (B,S,H,r)
         scale = mscale2 / ((dn + dr) ** 0.5)
         if pages is not None:
-            if s == 1:
-                c_cache = scatter_token_pages(
-                    cache["c"], c[:, 0, :], pages, index, seq_axis=1
-                )
-                kr_cache = scatter_token_pages(
-                    cache["kr"], kr[:, 0, 0, :], pages, index, seq_axis=1
-                )
-            else:  # extend chunk
-                c_cache = scatter_chunk_pages(
-                    cache["c"], c, pages, index, seq_axis=1
-                )
-                kr_cache = scatter_chunk_pages(
-                    cache["kr"], kr[:, :, 0, :], pages, index, seq_axis=1
-                )
+            c_cache = scatter_pages(cache["c"], c, pages, index, layer)
+            kr_cache = scatter_pages(
+                cache["kr"], kr[:, :, 0, :], pages, index, layer
+            )
             ctx = blocks.call(
                 "paged_attention",
                 jnp.swapaxes(q_abs, 1, 2),  # (B,H,S,r)
-                c_cache[:, None],  # latent pool as 1-KV-head (P,1,ps,r)
-                c_cache[:, None],  # ...and it doubles as the value pool
-                pages, index,
+                c_cache,  # the latent pool: one KV head of width r
+                c_cache,  # ...and it doubles as the value pool
+                pages, index, layer,
                 q_rope=jnp.swapaxes(qr, 1, 2),  # (B,H,S,dr)
-                kr_pool=kr_cache[:, None],
+                kr_pool=kr_cache,
                 scale=scale,
             )
             ctx = jnp.swapaxes(ctx, 1, 2)  # (B,S,H,r)
@@ -401,8 +397,11 @@ def mla_forward(
 
 
 def attention_forward(
-    p, x, cfg, positions, cache=None, index=None, mode="train", pages=None
+    p, x, cfg, positions, cache=None, index=None, mode="train", pages=None,
+    layer=None,
 ):
     if cfg.mla is not None:
-        return mla_forward(p, x, cfg, positions, cache, index, mode, pages)
-    return gqa_forward(p, x, cfg, positions, cache, index, mode, pages)
+        return mla_forward(
+            p, x, cfg, positions, cache, index, mode, pages, layer
+        )
+    return gqa_forward(p, x, cfg, positions, cache, index, mode, pages, layer)

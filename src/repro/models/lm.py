@@ -201,7 +201,7 @@ def init_cache(
 
 def _apply_attn_block(
     lp: dict, x: jax.Array, cfg: ArchConfig, positions, cache, index, mode,
-    kind: str, pages=None,
+    kind: str, pages=None, layer=None,
 ):
     cd = jnp.dtype(cfg.compute_dtype)
     # Sequence-parallel <-> tensor-parallel transitions are made explicit
@@ -217,7 +217,7 @@ def _apply_attn_block(
         rmsnorm(lp["ln1"], x, cfg.norm_eps).astype(cd)
     )
     attn_out, new_cache = attention_forward(
-        lp["attn"], h_in, cfg, positions, cache, index, mode, pages
+        lp["attn"], h_in, cfg, positions, cache, index, mode, pages, layer
     )
     x = x + attn_out.astype(x.dtype)
     ff_in = jax.lax.optimization_barrier(
@@ -256,34 +256,48 @@ def _apply_group(
     pages=None,
 ):
     """Scan a homogeneous group of layers; returns (x, aux_sum, load_sum,
-    new_gcache)."""
+    new_gcache).
+
+    A paged attention group's cache (``pages`` given) rides in the scan's
+    carry, not its ``xs``/``ys``: layer ``i`` writes its new token rows at
+    ``(i, page, row)`` of the stacked pools and reads ``pool[i, pages]``
+    inside its page walk, so no layer's pool is sliced out and no stacked
+    pool is re-emitted — with the cache donated, the pools are updated in
+    place.  Other caches are scanned per layer."""
     use_cache = gcache is not None
     shared = g.kind == "s"
+    carried = use_cache and pages is not None and g.kind != "m"
 
-    def apply_one(x, aux, load, lp, lcache):
+    def apply_one(x, aux, load, lp, lcache, li=None):
         p = shared_params if shared else lp
         if g.kind == "m":
             x, a, ld, nc = _apply_mamba_block(p, x, cfg, lcache, mode)
         else:
             x, a, ld, nc = _apply_attn_block(
-                p, x, cfg, positions, lcache, index, mode, g.kind, pages
+                p, x, cfg, positions, lcache, index, mode, g.kind, pages, li
             )
         return x, aux + a, load + ld, nc
 
     def layer(carry, xs):
-        x, aux, load = carry
+        x, aux, load, pool = carry
         # barrier: prevents XLA from hoisting dtype converts of the stacked
         # layer-input residuals out of the scan (an f32 copy of every
         # saved carry doubles remat memory otherwise)
         x = jax.lax.optimization_barrier(x)
-        if shared:
+        li = None
+        if carried:
+            lp, li = (None, xs) if shared else xs
+            lcache = pool
+        elif shared:
             lp, lcache = None, xs
         elif use_cache:
             lp, lcache = xs
         else:
             lp, lcache = xs, None
-        x, aux, load, nc = apply_one(x, aux, load, lp, lcache)
-        return (x, aux, load), nc
+        x, aux, load, nc = apply_one(x, aux, load, lp, lcache, li)
+        if carried:
+            return (x, aux, load, nc), None
+        return (x, aux, load, None), nc
 
     body = layer
     if cfg.remat == "full" and mode == "train":
@@ -301,13 +315,22 @@ def _apply_group(
             x, aux_t, load_t, _ = apply_one(x, aux_t, load_t, None, None)
         return x, aux_t, load_t, None
 
+    if carried:
+        layers = jnp.arange(g.count, dtype=jnp.int32)
+        xs = layers if shared else (gparams, layers)
+        (x, aux, load, new_cache), _ = jax.lax.scan(
+            body, (x, zero, _no_load(), gcache), xs
+        )
+        return x, aux, load, new_cache
     if shared:
         xs = gcache  # scan each site's cache under the shared params
     elif use_cache:
         xs = (gparams, gcache)
     else:
         xs = gparams
-    (x, aux, load), new_cache = jax.lax.scan(body, (x, zero, _no_load()), xs)
+    (x, aux, load, _), new_cache = jax.lax.scan(
+        body, (x, zero, _no_load(), None), xs
+    )
     return x, aux, load, (new_cache if use_cache else None)
 
 

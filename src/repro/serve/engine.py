@@ -51,7 +51,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import math
 import time
 from typing import Any, Iterable, Sequence
 
@@ -361,16 +360,16 @@ class ServeEngine:
                 cfg, n_slots, max_len, page_size=page_size, n_pages=n_pages
             )
             # (layers, block tokens) of each attention group's page walk,
-            # sized as the paged_attention kernel sizes it from its K pool
-            # (MLA's latent pool is its K)
+            # sized as the paged_attention kernel sizes it from a token row
+            # of its K pool (MLA's latent pool is its K)
             self._walks = []
             for key, kind in self._group_kinds.items():
                 if kind == "m":
                     continue
                 group = self.cache[key]
                 pool = group["k"] if "k" in group else group["c"]
-                layers, _, *page = pool.shape
-                token_bytes = math.prod(page) // page_size * pool.dtype.itemsize
+                layers, _, _, width = pool.shape
+                token_bytes = width * pool.dtype.itemsize
                 self._walks.append(
                     (layers, block_tokens(page_size, token_bytes, max_pages))
                 )

@@ -32,8 +32,7 @@ from repro.kernels.paged_attention import (
     block_tokens,
     paged_attention_pallas,
     paged_attention_xla,
-    scatter_chunk_pages,
-    scatter_token_pages,
+    scatter_pages,
     walk_blocks,
     walk_plan,
 )
@@ -48,20 +47,34 @@ F32 = dataclasses.replace(CFG, compute_dtype="float32", remat="none")
 # -- paged operand builder + dense float64 oracle ------------------------------
 
 
+#: the stacked pools hold two layers; the kernels read layer 1
+LAYERS, LAYER = 2, 1
+
+
+def _pool(rng, n_total, ps, width):
+    """Stacked token-major pools ``(LAYERS, n_total, ps, width)`` whose
+    other layer and null page are poisoned: reading either shows."""
+    pool = rng.standard_normal((LAYERS, n_total, ps, width))
+    pool = pool.astype(np.float32)
+    pool[:LAYER] = 1e6
+    pool[:, n_total - 1] = 1e6  # masked rows must never contribute
+    return pool
+
+
 def _paged_case(rng, *, b, h, kh, s, dk, dv, ps, mp, lengths, dr=0):
     """Identity-table paged operands with per-slot logical lengths.
 
     ``lengths[i]`` is slot ``i``'s history length (== the first new-token
     position); table entries past the pages needed to hold
-    ``lengths[i] + s`` tokens point at the null page, whose contents are
-    poisoned to catch any unmasked read.
+    ``lengths[i] + s`` tokens point at the null page.  The pools are
+    stacked token-major ``(LAYERS, P_total, ps, KH * D)`` and read at
+    ``LAYER``; the null page and the other layer are poisoned to catch
+    any unmasked or misaddressed read.
     """
     n_pages = b * mp
     null = n_pages
-    k_pool = rng.standard_normal((n_pages + 1, kh, ps, dk)).astype(np.float32)
-    v_pool = rng.standard_normal((n_pages + 1, kh, ps, dv)).astype(np.float32)
-    k_pool[null] = 1e6  # poison: masked rows must never contribute
-    v_pool[null] = 1e6
+    k_pool = _pool(rng, n_pages + 1, ps, kh * dk)
+    v_pool = _pool(rng, n_pages + 1, ps, kh * dv)
     q = rng.standard_normal((b, h, s, dk)).astype(np.float32)
     pages = np.arange(n_pages, dtype=np.int32).reshape(b, mp)
     for i, ln in enumerate(lengths):
@@ -74,11 +87,10 @@ def _paged_case(rng, *, b, h, kh, s, dk, dv, ps, mp, lengths, dr=0):
         "v_pool": jnp.asarray(v_pool),
         "pages": jnp.asarray(pages),
         "index": jnp.asarray(index),
+        "layer": jnp.asarray(LAYER, jnp.int32),
     }
     if dr:
-        kr_pool = rng.standard_normal((n_pages + 1, 1, ps, dr))
-        kr_pool = kr_pool.astype(np.float32)
-        kr_pool[null] = 1e6
+        kr_pool = _pool(rng, n_pages + 1, ps, dr)
         case["q_rope"] = jnp.asarray(
             rng.standard_normal((b, h, s, dr)).astype(np.float32)
         )
@@ -95,12 +107,14 @@ def _oracle(case):
     v_pool = np.asarray(case["v_pool"], np.float64)
     pages = np.asarray(case["pages"])
     index = np.asarray(case["index"])
-    kh, ps = k_pool.shape[1], k_pool.shape[2]
+    layer = int(case["layer"])
+    ps = k_pool.shape[2]
+    kh = k_pool.shape[-1] // dk
     g = h // kh
 
-    def view(pool):  # (b, mp, kh, ps, d) -> (b, kh, mp*ps, d)
-        v = pool[pages]
-        return np.moveaxis(v, 2, 1).reshape(b, kh, -1, pool.shape[-1])
+    def view(pool):  # the layer's (P, ps, kh*d) -> (b, kh, mp*ps, d)
+        v = pool[layer][pages].reshape(b, -1, kh, pool.shape[-1] // kh)
+        return np.moveaxis(v, 2, 1)
 
     kv, vv = view(k_pool), view(v_pool)
     qg = q.reshape(b, kh, g, s, dk)
@@ -121,7 +135,7 @@ def _oracle(case):
     p = np.exp(sc - sc.max(-1, keepdims=True))
     p = p / p.sum(-1, keepdims=True)
     o = np.einsum("bkgqt,bktd->bkgqd", p, vv)
-    return o.reshape(b, h, s, v_pool.shape[-1])
+    return o.reshape(b, h, s, vv.shape[-1])
 
 
 # lengths exercise: index 0 (empty history), a write landing exactly on a
@@ -189,7 +203,8 @@ def test_paged_block_call_dispatches(rng):
     for target in ("xla", "pallas"):
         with blocks.bind({"paged_attention": target}):
             got = blocks.call("paged_attention", *(
-                case[k] for k in ("q", "k_pool", "v_pool", "pages", "index")
+                case[k]
+                for k in ("q", "k_pool", "v_pool", "pages", "index", "layer")
             ))
         np.testing.assert_allclose(np.asarray(got), want,
                                    rtol=1e-4, atol=1e-4)
@@ -224,7 +239,7 @@ def _walk_case(rng, monkeypatch, name, s, layout):
     pages = np.array(case["pages"])
     index = np.array(case["index"])
     for i in set(range(len(lengths))) - set(live):
-        pages[i] = case["k_pool"].shape[0] - 1
+        pages[i] = case["k_pool"].shape[1] - 1
         index[i] = IDLE
     case["pages"], case["index"] = jnp.asarray(pages), jnp.asarray(index)
     return case, live
@@ -235,7 +250,7 @@ def _walk_case(rng, monkeypatch, name, s, layout):
 @pytest.mark.parametrize("name", sorted(WALK_CASES))
 def test_block_walk_matches_oracle(name, s, layout, rng, monkeypatch):
     case, live = _walk_case(rng, monkeypatch, name, s, layout)
-    assert block_tokens(8, case["k_pool"].shape[1] * 32 * 4, 16) == 24
+    assert block_tokens(8, case["k_pool"].shape[-1] * 4, 16) == 24
     got = np.asarray(jax.jit(paged_attention_xla)(**case))
     np.testing.assert_allclose(got[live], _oracle(case)[live],
                                rtol=1e-5, atol=1e-5)
@@ -257,11 +272,12 @@ def test_walk_trips_match_host_mirror(name, s, layout, rng, monkeypatch):
     trips = jax.jit(
         lambda pool, pages, index: walk_plan(pool, pages, index, s)[1].max()
     )(pool, case["pages"], case["index"])
-    _, kh, ps, d = pool.shape
-    block = block_tokens(ps, kh * d * pool.dtype.itemsize, 16)
+    # the engine sizes the block from a layer's page: (page, row width)
+    _, n_total, ps, width = pool.shape
+    block = block_tokens(ps, width * pool.dtype.itemsize, 16)
     host = walk_blocks(
         np.asarray(case["index"]), np.asarray(case["pages"]), s,
-        page_size=ps, block=block, null_page=pool.shape[0] - 1,
+        page_size=ps, block=block, null_page=n_total - 1,
     )
     assert int(trips) == int(host.max())
     longest = max(int(case["index"][i]) for i in live) + s
@@ -274,7 +290,7 @@ def test_block_walk_all_idle_batch_is_finite(layout, rng, monkeypatch):
     # every row a null-page row: the walk makes no trip, and the
     # zero-sum guard keeps 0/0 out of the result
     case, _ = _walk_case(rng, monkeypatch, "prefix", 1, layout)
-    null = case["k_pool"].shape[0] - 1
+    null = case["k_pool"].shape[1] - 1
     case["pages"] = jnp.full_like(case["pages"], null)
     case["index"] = jnp.full_like(case["index"], IDLE)
     got = np.asarray(jax.jit(paged_attention_xla)(**case))
@@ -304,17 +320,26 @@ def test_engine_index_mirror_tracks_device(rng):
 
 
 def test_scatter_chunk_matches_token_scatter(rng):
-    pool = jnp.zeros((5, 2, 4, 8), jnp.float32)
+    """A 3-token chunk written at once equals three one-token writes, and
+    lands token-major in its layer only: row ``(index + i) % 4`` of page
+    ``pages[b, (index + i) // 4]``."""
+    pool = jnp.zeros((2, 5, 4, 16), jnp.float32)  # (L, P, ps, KH*D)
     pages = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     index = jnp.asarray([3, 1], jnp.int32)  # chunk crosses a page boundary
-    val = jnp.asarray(rng.standard_normal((2, 2, 3, 8)), jnp.float32)
-    got = scatter_chunk_pages(pool, val, pages, index, seq_axis=2)
+    val = jnp.asarray(rng.standard_normal((2, 3, 16)), jnp.float32)
+    got = scatter_pages(pool, val, pages, index, 1)
     want = pool
     for i in range(3):
-        want = scatter_token_pages(
-            want, val[:, :, i], pages, index + i, seq_axis=2
-        )
+        want = scatter_pages(want, val[:, i:i + 1], pages, index + i, 1)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got[0], 0.0)
+    for b in range(2):
+        for i in range(3):
+            t = int(index[b]) + i
+            np.testing.assert_array_equal(
+                got[1, int(pages[b, t // 4]), t % 4], np.asarray(val[b, i])
+            )
 
 
 # -- shelf metadata: import-order independence + coverage ----------------------

@@ -10,6 +10,8 @@ preempted requests token-identically.
 
 import dataclasses
 
+import jax
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -20,6 +22,16 @@ CFG = get_config("llama3.2-1b").reduced()
 # parity tests compare token sequences across different programs: f32
 # keeps argmax ties deterministic across program shapes
 F32 = dataclasses.replace(CFG, compute_dtype="float32", remat="none")
+
+
+#: the two paged layouts: GQA rows of KH·D, MLA's latent and rope rows
+LAYOUTS = {
+    "gqa": F32,
+    "mla": dataclasses.replace(
+        get_config("deepseek-v2-236b").reduced(), compute_dtype="float32",
+        remat="none",
+    ),
+}
 
 
 def _prompt(rng, n):
@@ -127,6 +139,118 @@ def test_paged_matches_contiguous_greedy_staggered(rng):
     )
     assert _run_trace(degenerate, prompts, gens) == expected
     assert degenerate.kv.max_pages == 1  # one page per slot == contiguous
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_paged_decode_matches_contiguous_over_32_steps(layout, rng):
+    """Decoding through the in-place page writes gives the contiguous
+    cache's tokens over 32 steps, for GQA and MLA pools alike."""
+    cfg = LAYOUTS[layout]
+    prompts = [_prompt(rng, n) for n in (5, 11, 3)]
+    gens = (32, 32, 20)
+    expected = _run_trace(
+        ServeEngine(cfg, n_slots=3, max_len=64, seed=0), prompts, gens
+    )
+    paged = ServeEngine(cfg, n_slots=3, max_len=64, seed=0, page_size=8)
+    assert _run_trace(paged, prompts, gens) == expected
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_decode_step_writes_only_its_token_rows(layout, rng):
+    """One engine decode step changes each layer's pool only at each
+    active slot's (page, row) of that step — one of them the first row of
+    a page the step opens — and an idle slot writes only the null page:
+    every other row of every layer is bit-identical."""
+    cfg = LAYOUTS[layout]
+    ps = 4
+    engine = ServeEngine(cfg, n_slots=3, max_len=32, seed=0, page_size=ps)
+    for n in (5, 7):  # 7: the second decode step writes position 8
+        engine.submit(Request(_prompt(rng, n), max_new_tokens=6))
+    engine.step()  # both admitted, one decode step
+    assert len(engine.scheduler.active) == 2  # slot 2 stays idle
+    before = jax.tree.map(np.array, engine.cache)
+    index = engine._dev_index.copy()
+    engine.step()
+    after = jax.tree.map(np.array, engine.cache)
+    table, null = engine.kv.array(), engine.kv.pool.null_page
+    want = {
+        (int(table[slot, index[slot] // ps]), int(index[slot] % ps))
+        for slot in engine.scheduler.active
+    }
+    assert (int(table[1, 2]), 0) in want  # the page this step opened
+    for key, group in before.items():
+        if key == "index":
+            continue
+        for leaf, old in group.items():
+            new = after[key][leaf]
+            assert new.shape == old.shape
+            assert new.shape[1:3] == (null + 1, ps)  # (L, P_total, ps, W)
+            changed = (old != new).any(axis=-1)  # (L, P_total, ps)
+            changed[:, null] = False
+            rows = {(int(p), int(r)) for _, p, r in np.argwhere(changed)}
+            assert rows == want, (key, leaf)
+            for p, r in want:  # ...in every layer of the group
+                assert changed[:, p, r].all(), (key, leaf, p, r)
+
+
+def test_paged_pool_rows_are_token_major():
+    """A page is ``(page_size, W)`` token rows, a row holding every KV
+    head side by side: GQA ``KH * D``, MLA's latent ``r`` and rope
+    ``dr`` pools; the null page is the pool's last."""
+    from repro.models import lm
+
+    for cfg in LAYOUTS.values():
+        cache = jax.eval_shape(
+            lambda cfg=cfg: lm.init_cache(cfg, 2, 32, page_size=8, n_pages=5)
+        )
+        if cfg.mla is None:
+            widths = {"k": cfg.n_kv_heads * cfg.d_head,
+                      "v": cfg.n_kv_heads * cfg.d_head}
+        else:
+            widths = {"c": cfg.mla.kv_lora_rank,
+                      "kr": cfg.mla.qk_rope_head_dim}
+        for g in lm.groups_of(cfg):
+            assert {k: a.shape for k, a in cache[g.key].items()} == {
+                k: (g.count, 6, 8, w) for k, w in widths.items()
+            }
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_insert_pages_writes_token_major_pages(layout, rng):
+    """A prefilled batch-1 contiguous cache leaf lands in the slot's
+    pages token-major: position ``t`` at row ``t % ps`` of page
+    ``page_ids[t // ps]``, its per-token axes flattened; the null page
+    absorbs the unallocated tail and no other page changes."""
+    import jax.numpy as jnp
+
+    from repro.models import lm
+    from repro.models.attention import cache_seq_axes, insert_pages
+
+    cfg, ps, max_len = LAYOUTS[layout], 4, 16
+    pools = lm.init_cache(cfg, 2, max_len, page_size=ps, n_pages=6)
+    b1 = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        lm.init_cache(cfg, 1, max_len),
+    )
+    page_ids = jnp.asarray([4, 1, 6, 6], jnp.int32)  # 2 pages, null tail
+    for key, group in pools.items():
+        if key == "index":
+            continue
+        for leaf, pool in group.items():
+            axis = cache_seq_axes(cfg)[leaf]
+            out = np.asarray(
+                insert_pages(pool, b1[key][leaf], page_ids, axis)
+            )
+            src = np.moveaxis(np.asarray(b1[key][leaf])[:, 0], axis, 1)
+            src = src.reshape(src.shape[0], max_len, -1)  # (L, S, W)
+            for t in range(2 * ps):
+                np.testing.assert_array_equal(
+                    out[:, int(page_ids[t // ps]), t % ps], src[:, t]
+                )
+            untouched = [0, 2, 3, 5]
+            np.testing.assert_array_equal(
+                out[:, untouched], np.asarray(pool)[:, untouched]
+            )
 
 
 def test_paged_admits_mix_contiguous_capacity_defers(rng):

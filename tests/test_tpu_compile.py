@@ -15,6 +15,7 @@ this file.
 from __future__ import annotations
 
 import functools
+import math
 import os
 import re
 
@@ -61,9 +62,10 @@ def _compile(fn, one_chip, *shapes):
 
 
 # llama3.2-1b serving widths: 32 heads, 8 KV heads, head dim 64, page 16,
-# 8 slots of 1024 tokens
+# 8 slots of 1024 tokens, 16 layers of stacked token-major pools
 SLOTS, H, KH, D, PAGE, MAX_PAGES = 8, 32, 8, 64, 16, 64
 N_POOL = SLOTS * MAX_PAGES + 1  # + the null page
+LAYERS = 16
 
 
 @pytest.mark.parametrize("s", [1, 16], ids=["decode", "extend"])
@@ -71,10 +73,11 @@ def test_paged_attention_gqa_compiles(one_chip, s):
     _compile(
         paged_attention_pallas, one_chip,
         ((SLOTS, H, s, D), BF16),
-        ((N_POOL, KH, PAGE, D), BF16),
-        ((N_POOL, KH, PAGE, D), BF16),
+        ((LAYERS, N_POOL, PAGE, KH * D), BF16),
+        ((LAYERS, N_POOL, PAGE, KH * D), BF16),
         ((SLOTS, MAX_PAGES), jnp.int32),
         ((SLOTS,), jnp.int32),
+        ((), jnp.int32),
     )
 
 
@@ -83,20 +86,21 @@ def test_paged_attention_mla_decode_compiles(one_chip):
     # rank 512, plus the 64-wide decoupled rope channel
     heads, rank, rope = 128, 512, 64
 
-    def mla(q, c_pool, q_rope, kr_pool, pages, index):
+    def mla(q, c_pool, q_rope, kr_pool, pages, index, layer):
         return paged_attention_pallas(
-            q, c_pool, c_pool, pages, index, q_rope=q_rope, kr_pool=kr_pool,
-            scale=1.0 / (128 + rope) ** 0.5,
+            q, c_pool, c_pool, pages, index, layer, q_rope=q_rope,
+            kr_pool=kr_pool, scale=1.0 / (128 + rope) ** 0.5,
         )
 
     _compile(
         mla, one_chip,
         ((SLOTS, heads, 1, rank), BF16),
-        ((N_POOL, 1, PAGE, rank), BF16),
+        ((LAYERS, N_POOL, PAGE, rank), BF16),
         ((SLOTS, heads, 1, rope), BF16),
-        ((N_POOL, 1, PAGE, rope), BF16),
+        ((LAYERS, N_POOL, PAGE, rope), BF16),
         ((SLOTS, MAX_PAGES), jnp.int32),
         ((SLOTS,), jnp.int32),
+        ((), jnp.int32),
     )
 
 
@@ -232,27 +236,22 @@ def _bench_module(name, path):
     return module
 
 
-def test_deepseek_v2_lite_cell_programs_fit_the_chip(one_chip):
-    """The deepseek-v2-lite cell at its published widths, its held expert
-    share and its engine size (``bench/configs/deepseek-v2-lite.json``):
-    the decode step over the whole page pool fits the sizing rule's share
-    of the chip's allocator limit and the longest prefill bucket beside
-    that pool fits the limit, compiled for the described chip; the expert
-    layers run the chip's grouped (ragged) kernel."""
+def _cell(one_chip, name):
+    """A benchmark configuration (``bench/configs/<name>.json``) at its
+    engine size, for the described chip: (config, engine, abstract
+    parameters, abstract page pools, a spec maker), the engine built
+    with a one-page pool (its programs take the pools as arguments)."""
     import json
     import pathlib
 
     from repro.models import lm
 
     root = pathlib.Path(__file__).resolve().parents[1]
-    config = json.loads(
-        (root / "bench/configs/deepseek-v2-lite.json").read_text())
-    adapter = _bench_module("adapter_mla_moe", "bench/adapters/mla_moe.py")
-    ref = _bench_module("reference_mla_moe", "bench/reference/mla_moe.py")
-    # the chip's allocator limit and the compiler's own reservation
-    limit = _bench_module("sizing", "bench/sizing.py")
+    config = json.loads((root / f"bench/configs/{name}.json").read_text())
+    family = config["family"]
+    adapter = _bench_module(f"adapter_{family}", f"bench/adapters/{family}.py")
+    ref = _bench_module(f"reference_{family}", f"bench/reference/{family}.py")
     eng = config["engine"]
-    slots, pages, max_len = eng["n_slots"], eng["n_pages"], eng["max_len"]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
@@ -265,22 +264,56 @@ def test_deepseek_v2_lite_cell_programs_fit_the_chip(one_chip):
     cache = jax.tree.map(
         lambda a: sds(a.shape, a.dtype),
         jax.eval_shape(lambda: lm.init_cache(
-            cfg, slots, max_len, page_size=eng["page_size"], n_pages=pages)),
+            cfg, eng["n_slots"], eng["max_len"], page_size=eng["page_size"],
+            n_pages=eng["n_pages"])),
     )
     engine = adapter.engine(dict(config, engine=dict(eng, n_pages=1)), cfg,
                             params, 0)
-    i32 = functools.partial(sds, dtype="int32")
-    decode = engine.programs.records["decode"].fn.lower(
-        params, i32((slots, 1)), cache, i32((slots, max_len // eng["page_size"])),
-        i32((slots,)), i32((slots,)), sds((slots,), "float32"), i32((slots,)),
-    ).compile()
+    return config, engine, params, cache, sds
+
+
+@pytest.fixture(scope="module")
+def cell_decode(one_chip):
+    """name -> (the cell's decode program over its whole page pool,
+    compiled for the described chip, once; the abstract pools)."""
+    compiled = {}
+
+    def get(name):
+        if name not in compiled:
+            config, engine, params, cache, sds = _cell(one_chip, name)
+            eng = config["engine"]
+            slots = eng["n_slots"]
+            i32 = functools.partial(sds, dtype="int32")
+            compiled[name] = engine.programs.records["decode"].fn.lower(
+                params, i32((slots, 1)), cache,
+                i32((slots, eng["max_len"] // eng["page_size"])),
+                i32((slots,)), i32((slots,)), sds((slots,), "float32"),
+                i32((slots,)),
+            ).compile(), cache
+        return compiled[name]
+
+    return get
+
+
+def test_deepseek_v2_lite_cell_programs_fit_the_chip(one_chip, cell_decode):
+    """The deepseek-v2-lite cell at its published widths, its held expert
+    share and its engine size (``bench/configs/deepseek-v2-lite.json``):
+    the decode step over the whole page pool fits the sizing rule's share
+    of the chip's allocator limit and the longest prefill bucket beside
+    that pool fits the limit, compiled for the described chip; the expert
+    layers run the chip's grouped (ragged) kernel."""
+    config, engine, params, cache, sds = _cell(one_chip, "deepseek-v2-lite")
+    # the chip's allocator limit and the compiler's own reservation
+    limit = _bench_module("sizing", "bench/sizing.py")
+    decode, _ = cell_decode("deepseek-v2-lite")
     m = decode.memory_analysis()
     # the sizing rule keeps its margin of the limit free
     assert (limit.RESERVED + m.argument_size_in_bytes + m.temp_size_in_bytes
             <= (1 - limit.MARGIN) * limit.BYTES_LIMIT)
     assert "ragged" in decode.as_text()
 
-    longest = max_len  # the mix's longest context rounds up to max_len
+    i32 = functools.partial(sds, dtype="int32")
+    longest = config["engine"]["max_len"]  # the mix's longest context
     prefill = engine.programs.records["prefill"].fn.lower(
         params, i32((1, longest)), i32(()), i32(()), i32(()),
         sds((), "float32"), i32(()),
@@ -289,3 +322,56 @@ def test_deepseek_v2_lite_cell_programs_fit_the_chip(one_chip):
     pool = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert (limit.RESERVED + p.argument_size_in_bytes + p.temp_size_in_bytes
             + p.output_size_in_bytes + pool) <= limit.BYTES_LIMIT
+
+
+#: each cell's decode program compiled for v5e while its layer loop still
+#: sliced every layer's pool out of the stacked cache and wrote a new
+#: stack: temporaries in bytes
+SLICED_LOOP_TEMPS = {"deepseek-v2-lite": 5.00e9, "stablelm-1.6b": 5.10e9}
+
+#: an instruction that moves a whole array: its name, element type, dims
+#: and opcode
+MOVE = re.compile(
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+    r"(copy|dynamic-slice|dynamic-update-slice)\("
+)
+
+
+@pytest.mark.parametrize("name", sorted(SLICED_LOOP_TEMPS))
+def test_decode_writes_the_page_pool_in_place(cell_decode, name):
+    """The decode program's layer loop carries the stacked page pools and
+    writes each new token's rows into them in place: no ``copy``,
+    ``dynamic-slice`` or ``dynamic-update-slice``, fused or not, moves a
+    whole layer's pool or a whole stack of them, and the temporaries fall
+    by at least 90% of the pools' bytes.  Exempt: MLA's 64-wide rope
+    pool, whose device layout keeps the page axis minor — at most two
+    whole-pool relayouts of each, outside the loop (into the loop's
+    layout and back), whose buffer the temporaries may hold."""
+    decode, cache = cell_decode(name)
+    sizes = {}  # element count -> leaf name, for a layer's pool and a stack
+    for key, group in cache.items():
+        if key == "index":
+            continue
+        for leaf, pool in group.items():
+            sizes[pool.size] = sizes[pool.size // pool.shape[0]] = leaf
+    entry = False
+    moves = []  # (leaf, in the entry computation, name, opcode)
+    for line in decode.as_text().splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        m = MOVE.match(line)
+        if m:
+            n = math.prod(int(d) for d in m.group(3).split(",") if d)
+            if m.group(2) == "bf16" and n in sizes:
+                moves.append((sizes[n], entry, m.group(1), m.group(4)))
+    assert [mv for mv in moves if mv[0] != "kr"] == []
+    rope = [mv for mv in moves if mv[0] == "kr"]
+    rope_pools = [g["kr"] for k, g in cache.items() if k != "index"
+                  and "kr" in g]
+    assert len(rope) <= 2 * len(rope_pools), rope
+    assert all(in_entry and op == "copy" for _, in_entry, _, op in rope)
+    pools = sum(a.size * a.dtype.itemsize for k, g in cache.items()
+                if k != "index" for a in g.values())
+    relayout = sum(a.size * a.dtype.itemsize for a in rope_pools)
+    temps = decode.memory_analysis().temp_size_in_bytes
+    assert temps - relayout < SLICED_LOOP_TEMPS[name] - 0.9 * pools, temps
